@@ -15,6 +15,9 @@ import time
 
 
 def main() -> None:
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="shorter streams (CI mode)")

@@ -109,17 +109,13 @@ def serial_step_proxy(stream, eps, num_segments):
     return int(wt.shape[1]), int(wt.shape[0])
 
 
-def _sharded_child(d: int, seconds: int, m: int, n: int, windows_ms,
-                   num_segments: int = 8):
-    """Body of one ``--devices`` child (this process's XLA_FLAGS already
-    forced ``d`` host devices): sharded streaming counter per window
-    size, exactness asserted, rows printed as one JSON line."""
-    import json
+def _sharded_rows(d: int, seconds: int, m: int, n: int, windows_ms,
+                  num_segments: int = 8):
+    """Sharded streaming counter per window size over this process's
+    devices (``d`` of them), exactness asserted; returns the rows."""
+    import jax
 
-    try:
-        from repro.kernels import ops as kops
-    except ImportError:
-        kops = None
+    from repro.kernels import ops as kops
 
     stream, truth = sym26_stream(seconds=seconds)
     eps = random_candidates(m, n,
@@ -128,7 +124,7 @@ def _sharded_child(d: int, seconds: int, m: int, n: int, windows_ms,
     rows = []
     for wms in windows_ms:
         windows = list(partition_windows(stream, wms))
-        calls0 = kops.KERNEL_CALLS["a1_mapc_shard"] if kops else 0
+        calls0 = kops.KERNEL_CALLS["a1_mapc_shard"]
         final, meter, ctr = bench_carry(windows, eps, "mapconcat_sharded",
                                         use_kernel=True,
                                         num_segments=num_segments)
@@ -140,8 +136,7 @@ def _sharded_child(d: int, seconds: int, m: int, n: int, windows_ms,
         # commit (spans too short for one stitch-safe segment per device);
         # tag the mode — and claim the d-way proxy division — only when
         # sharded launches actually ran
-        sharded_ran = (kops is not None
-                       and kops.KERNEL_CALLS["a1_mapc_shard"] > calls0)
+        sharded_ran = kops.KERNEL_CALLS["a1_mapc_shard"] > calls0
         d_eff = max(ctr._shard_d, 1) if sharded_ran else 1
         steps, p_eff = serial_step_proxy(stream, eps,
                                          max(num_segments, d_eff))
@@ -150,6 +145,7 @@ def _sharded_child(d: int, seconds: int, m: int, n: int, windows_ms,
                 else ("kernel" if ctr._mapc_kernel else "fallback-xla"))
         rows.append({
             "label": f"mapcs/w{wms}/d{d}", "seconds": s["seconds"],
+            "platform": jax.devices()[0].platform,
             "devices": d_eff, "segments": p_eff,
             "windows": s["windows"], "events": s["events"],
             "ev_per_s": round(s["events_per_sec"]),
@@ -157,37 +153,57 @@ def _sharded_child(d: int, seconds: int, m: int, n: int, windows_ms,
             "serial_steps_per_device": per_dev,
             "proxy_speedup_vs_1dev": round(p_eff * steps / per_dev, 3),
             "mapc_mode": mode})
-    print(json.dumps(rows))
+    return rows
 
 
-def _sharded_sweep(rep, devices, seconds, m, n, windows_ms, kernel):
-    """Parent side of ``--devices``: one subprocess per device count (the
-    forced-host-device flag must precede the jax import)."""
+def _emulated_rows(d, seconds, m, n, windows_ms, kernel):
+    """One CPU child with ``d`` forced host devices (the flag must precede
+    its jax import). ``JAX_PLATFORMS=cpu`` keeps the child off any
+    accelerator the parent may hold. Returns its rows, or None."""
     import json
     import subprocess
 
     script = Path(__file__).resolve()
     root = script.parent.parent
-    for d in devices:
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={d}"
-        env["PYTHONPATH"] = str(root / "src")
-        if kernel == "interpret":
-            env["REPRO_KERNEL_INTERPRET"] = "1"
-        cmd = [sys.executable, str(script), "--sharded-child", str(d),
-               "--seconds", str(seconds), "--m", str(m), "--n", str(n),
-               "--windows-ms"] + [str(w) for w in windows_ms]
-        out = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                             cwd=str(root))
-        if out.returncode != 0:
-            print(f"[stream-bench] devices={d} sweep failed:\n"
-                  f"{out.stderr[-2000:]}", file=sys.stderr)
-            continue
-        for row in json.loads(out.stdout.strip().splitlines()[-1]):
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={d}"
+    env["PYTHONPATH"] = str(root / "src")
+    if kernel == "interpret":
+        env["REPRO_KERNEL_INTERPRET"] = "1"
+    cmd = [sys.executable, str(script), "--sharded-child", str(d),
+           "--seconds", str(seconds), "--m", str(m), "--n", str(n),
+           "--windows-ms"] + [str(w) for w in windows_ms]
+    out = subprocess.run(cmd, env=env, capture_output=True, text=True,
+                         cwd=str(root))
+    if out.returncode != 0:
+        print(f"[stream-bench] devices={d} sweep failed:\n"
+              f"{out.stderr[-2000:]}", file=sys.stderr)
+        return None
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def _sharded_sweep(rep, devices, seconds, m, n, windows_ms, kernel):
+    """``--devices``: with a TPU, one in-process run over the host's real
+    devices (the chip belongs to this process); without one, a
+    forced-host-device CPU emulation per requested device count."""
+    import jax
+
+    from repro.core.mapconcat import shard_device_count
+
+    if jax.default_backend() == "tpu":
+        sweeps = [_sharded_rows(shard_device_count(), seconds, m, n,
+                                windows_ms)]
+    else:
+        sweeps = [_emulated_rows(d, seconds, m, n, windows_ms, kernel)
+                  for d in devices]
+    for rows in sweeps:
+        for row in rows or ():
             label = row.pop("label")
             seconds_row = row.pop("seconds")
             rep.add(label, seconds_row, **row)
-            print(f"[stream-bench] {label} ({row['mapc_mode']}): "
+            print(f"[stream-bench] {label} ({row['mapc_mode']}, "
+                  f"{row['platform']}): "
                   f"{row['steady_ev_per_s']:,} ev/s steady, "
                   f"{row['serial_steps_per_device']} serial steps/device "
                   f"({row['proxy_speedup_vs_1dev']:.2f}x vs 1-dev)")
@@ -314,15 +330,17 @@ def main():
                     help="in-kernel MapConcatenate sweep: one "
                          "segmented-kernel run per (window size, P)")
     ap.add_argument("--devices", type=int, nargs="*", default=[],
-                    help="mesh-sharded sweep: one forced-host-device-count "
-                         "subprocess per d, sharded streaming counter per "
-                         "window size")
+                    help="mesh-sharded sweep: on a TPU host one run over "
+                         "the real devices; elsewhere one forced-host-"
+                         "device-count CPU subprocess per d, sharded "
+                         "streaming counter per window size")
     ap.add_argument("--sharded-child", type=int, default=None,
                     help=argparse.SUPPRESS)  # internal: --devices child
     args = ap.parse_args()
     if args.sharded_child is not None:
-        _sharded_child(args.sharded_child, args.seconds, args.m, args.n,
-                       args.windows_ms)
+        import json
+        print(json.dumps(_sharded_rows(args.sharded_child, args.seconds,
+                                       args.m, args.n, args.windows_ms)))
         return
     run(seconds=args.seconds, m=args.m, n=args.n,
         windows_ms=args.windows_ms, engine=args.engine, kernel=args.kernel,

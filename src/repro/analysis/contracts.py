@@ -17,9 +17,13 @@ new engine (or a refactor of an old one) cannot quietly drop them:
          Brick shapes must come from the shared layout contract
          (``LANES``/``SUBLANES``/``lcap``/``block_e`` names) so kernel
          and host packers cannot drift apart.
-  KC105  ``except NotImplementedError`` degradation arm around kernel
-         dispatch that never calls ``record_fallback``.  Silent
-         downgrades are invisible to telemetry and benchmarks.
+  KC105  degradation arm around kernel dispatch that catches more than
+         the dispatcher's own decline (``KernelDeclined``) — a bare
+         ``NotImplementedError`` is also what a Pallas lowering raises,
+         and ``ImportError`` hides a broken install, so either would
+         reroute a kernel fault onto the XLA scan — or that never calls
+         ``record_fallback``.  Silent downgrades are invisible to
+         telemetry and benchmarks.
   KC106  direct ``os.environ`` read of the interpret-mode variables
          outside ``kernels/tally.py``.  One accessor
          (``interpret_requested``) owns the env aliases.
@@ -154,10 +158,19 @@ def lint_source(source: str, path: str) -> list[Finding]:
                         "shapes from the layout contract "
                         "(LANES/SUBLANES/lcap/block_e)"))
 
-        # KC105 — unrecorded kernel→XLA degradation
+        # KC105 — kernel→XLA degradation that is too broad or unrecorded
         if isinstance(node, ast.Try) and _uses_kernel_plane(node.body):
             for h in node.handlers:
-                if "NotImplementedError" not in _handler_names(h):
+                names = _handler_names(h)
+                broad = names & {"NotImplementedError", "ImportError"}
+                if broad:
+                    findings.append(Finding(
+                        "KC105", path, h.lineno,
+                        f"degradation arm catches {sorted(broad)} — a "
+                        "kernel lowering/compile fault would reroute "
+                        "silently; catch KernelDeclined only"))
+                    continue
+                if "KernelDeclined" not in names:
                     continue
                 body = ast.Module(body=h.body, type_ignores=[])
                 if not _contains_call(body, "record_fallback"):

@@ -30,9 +30,10 @@ from __future__ import annotations
 
 import inspect
 import logging
-import re
 
 import numpy as np
+
+from repro.obs.jaxprof import _COMPILE_RE
 
 from .findings import Finding
 
@@ -48,7 +49,6 @@ MONITORED_COMPILES = (
 )
 COMPILE_BUDGET = 2  # per monitored entry point per session
 
-_COMPILE_RE = re.compile(r"Compiling ([\w.<>-]+) with global shapes")
 
 
 # ---------------------------------------------------------------- jaxpr

@@ -2,17 +2,21 @@
 
 Recomputes, from the layout contracts alone, the VMEM-resident bytes of
 each Pallas launch the dispatch policy can admit — the same arithmetic
-the BlockSpecs in ``kernels/a1_count.py`` / ``a2_count.py`` imply — and
-fails any admitted configuration whose footprint exceeds the budget.
-Before this pass, an oversized commit window overflowed VMEM with a
-Mosaic allocation error at runtime as the only signal; now the admission
-bound (``ops.MAX_SEG_BRICK_LW``) is checked against the budget at audit
-time, and the runtime guard in ``ops.segment_bricks`` keeps the bound.
+the BlockSpecs and scratch shapes in ``kernels/a1_count.py`` /
+``a2_count.py`` imply — and fails any admitted configuration whose
+footprint exceeds the budget. The event and segment bricks stream
+through SMEM in chunks of at most ``DEFAULT_BLOCK_E`` events (the kernels
+read events as scalars), so no VMEM term grows with the stream or the
+segment window; the window length only has to agree with the chunk
+quantum (VM303). The compiler has the final say:
+``tests/test_tpu_compile.py`` compiles the kernels for a described v5e.
 
 The model is deliberately conservative:
   * every operand block is counted on both the input and output side
-    (aliased pairs included — Mosaic still windows both), and
-  * everything is doubled for the pipeline's double buffering.
+    (aliased pairs included — Mosaic still windows both),
+  * operand blocks are doubled for the pipeline's double buffering, and
+  * the segmented kernels' machine-state scratch (K = N phases × LCAP
+    slot rows, K pointer rows, the phase-row brick) is counted once.
 
 Per-block bytes = prod(block shape) × 4 (the counting plane is i32-only,
 enforced by the Pass 2 dtype rule).
@@ -34,8 +38,8 @@ from .findings import Finding
 LANES = 128
 SUBLANES = 8
 SEG_ROWS = 5
+MAPC_ROWS = 6  # per-phase rows of the segmented kernels' scratch
 DEFAULT_BLOCK_E = 1024
-EV_ROWS = 3  # types; times; dup
 
 ITEM_BYTES = 4    # i32 everywhere in the counting plane
 DOUBLE_BUF = 2    # Pallas pipeline double buffering
@@ -65,12 +69,11 @@ def _blocks_bytes(blocks) -> int:
     return total * ITEM_BYTES * DOUBLE_BUF
 
 
-def a1_state_footprint(n_levels: int, lcap: int, block_m: int = LANES,
-                       block_e: int = DEFAULT_BLOCK_E) -> int:
+def a1_state_footprint(n_levels: int, lcap: int,
+                       block_m: int = LANES) -> int:
     """VMEM bytes of one ``a1_count_state_kernel`` grid step."""
     np_ = _round_up(max(n_levels, 1), SUBLANES)
     ins = ([(np_, block_m)] * 3            # et / tlo / thi
-           + [(EV_ROWS, block_e)]          # event chunk
            + [(np_, lcap, block_m)] * 2    # s / po bricks
            + [(SUBLANES, block_m)] * 2)    # cnt / ovf
     outs = ([(SUBLANES, block_m)] * 2      # cnt / ovf
@@ -78,30 +81,28 @@ def a1_state_footprint(n_levels: int, lcap: int, block_m: int = LANES,
     return _blocks_bytes(ins + outs)
 
 
-def a2_state_footprint(n_levels: int, block_m: int = LANES,
-                       block_e: int = DEFAULT_BLOCK_E) -> int:
+def a2_state_footprint(n_levels: int, block_m: int = LANES) -> int:
     """VMEM bytes of one ``a2_count_state_kernel`` grid step."""
     np_ = _round_up(max(n_levels, 1), SUBLANES)
     ins = ([(np_, block_m)] * 3            # et / tlo / thi
-           + [(EV_ROWS, block_e)]          # event chunk
            + [(np_, block_m)]              # s tile
            + [(SUBLANES, block_m)])        # cnt
     outs = [(SUBLANES, block_m), (np_, block_m)]
     return _blocks_bytes(ins + outs)
 
 
-def mapconcat_footprint(n_levels: int, lw: int,
+def mapconcat_footprint(n_levels: int, lcap: int = MAX_LCAP,
                         block_m: int = LANES) -> int:
-    """VMEM bytes of one segmented (MapConcatenate) kernel grid step.
-
-    The A1 and A2 variants have identical block sets — ``lcap`` affects
-    only in-register state, not the windowed operands."""
+    """VMEM bytes of one segmented (MapConcatenate) kernel grid step: the
+    windowed operands plus the machine-state scratch of the A1 variant
+    (the A2 variant's single slot per phase is smaller)."""
     np_ = _round_up(max(n_levels, 1), SUBLANES)
     ins = ([(np_, block_m)] * 4            # et / tlo / thi / cum
-           + [(SUBLANES, block_m)]         # w
-           + [(1, SEG_ROWS, lw)])          # segment event brick
+           + [(SUBLANES, block_m)])        # w
     outs = [(np_, block_m)] * 4 + [(SUBLANES, block_m)]
-    return _blocks_bytes(ins + outs)
+    k = n_levels
+    scratch = (k * lcap + k + MAPC_ROWS) * np_ * block_m * ITEM_BYTES
+    return _blocks_bytes(ins + outs) + scratch
 
 
 def check_vmem(max_seg_brick_lw: int,
@@ -132,22 +133,21 @@ def check_vmem(max_seg_brick_lw: int,
                 "VM301", _POLICY_PATH, 0,
                 f"a2 state launch (N={n}) needs {b / 2**20:.1f} MiB "
                 f"VMEM > budget {budget / 2**20:.1f} MiB"))
-        # largest admitted segment window — the policy constant under test
-        b = mapconcat_footprint(n, max_seg_brick_lw)
+        b = mapconcat_footprint(n)
         worst["mapconcat"] = max(worst["mapconcat"], b)
         if b > budget:
             findings.append(Finding(
                 "VM302", _POLICY_PATH, 0,
-                f"segmented launch (N={n}, LW={max_seg_brick_lw}) needs "
+                f"segmented launch (N={n}, lcap={MAX_LCAP}) needs "
                 f"{b / 2**20:.1f} MiB VMEM > budget "
-                f"{budget / 2**20:.1f} MiB — lower MAX_SEG_BRICK_LW"))
+                f"{budget / 2**20:.1f} MiB"))
 
-    if max_seg_brick_lw % LANES:
+    if max_seg_brick_lw % DEFAULT_BLOCK_E:
         findings.append(Finding(
             "VM303", _POLICY_PATH, 0,
             f"MAX_SEG_BRICK_LW={max_seg_brick_lw} is not a multiple of "
-            f"the {LANES}-lane window padding — admission and padding "
-            "quanta must agree"))
+            f"the {DEFAULT_BLOCK_E}-event SMEM chunk — an admitted window "
+            "would be streamed as one whole-window chunk"))
 
     summary = {f"vmem_worst_{k}_bytes": v for k, v in worst.items()}
     summary["vmem_budget_bytes"] = budget
@@ -184,7 +184,7 @@ def check_calibration_grid(points, max_seg_brick_lw: int,
                 "engines would decline and the fit would mislabel the "
                 "XLA fallback"))
             continue
-        b = mapconcat_footprint(n_ep, lw)
+        b = mapconcat_footprint(n_ep)
         worst_bytes = max(worst_bytes, b)
         if b > budget:
             findings.append(Finding(

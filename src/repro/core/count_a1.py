@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.tally import record_fallback
+from repro.kernels.tally import KernelDeclined, record_fallback
 
 from . import ref
 from .episodes import EpisodeBatch
@@ -219,7 +219,7 @@ def count_a1(stream: EventStream, eps: EpisodeBatch,
                 counts, _, new_state = kops.a1_count_stateful(
                     stream, eps, state=state, lcap=lcap)
                 return counts, new_state
-            except (ImportError, NotImplementedError):
+            except KernelDeclined:
                 record_fallback("a1_stateful")
         out = count_a1_vectorized(stream, eps, lcap=lcap, state=state,
                                   return_state=True)
@@ -229,7 +229,7 @@ def count_a1(stream: EventStream, eps: EpisodeBatch,
         try:
             from repro.kernels import ops as kops
             counts, ovf = kops.a1_count(stream, eps, lcap=lcap)
-        except (ImportError, NotImplementedError):
+        except KernelDeclined:
             record_fallback("a1_count")
             counts, ovf = count_a1_vectorized(stream, eps, lcap=lcap)
     else:

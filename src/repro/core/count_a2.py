@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.tally import record_fallback
+from repro.kernels.tally import KernelDeclined, record_fallback
 
 from .episodes import EpisodeBatch
 from .events import TIME_NEG_INF, EventStream, count_level1
@@ -138,7 +138,7 @@ def count_single_slot(stream: EventStream, eps: EpisodeBatch,
             if return_state:
                 return counts, new_state
             return counts
-        except (ImportError, NotImplementedError):
+        except KernelDeclined:
             record_fallback("a2_stateful")
     st = state if state is not None else init_a2_state(eps)
     s, count = _a2_carry_scan()(
@@ -198,12 +198,12 @@ def count_a2(stream: EventStream, eps: EpisodeBatch,
                 counts[idx] = count_single_slot(stream, relaxed.select(idx),
                                                 inclusive_lower=True)
             return counts
-        except (ImportError, NotImplementedError):
+        except KernelDeclined:
             record_fallback("a2_segments")
     if use_kernel:
         try:
             from repro.kernels import ops as kops
             return kops.a2_count(stream, relaxed)
-        except (ImportError, NotImplementedError):
+        except KernelDeclined:
             record_fallback("a2_count")
     return count_single_slot(stream, relaxed, inclusive_lower=True)

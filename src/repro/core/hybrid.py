@@ -12,7 +12,7 @@ the linear one; we re-fit a, b on this host in benchmarks/fig8).
 
 from __future__ import annotations
 
-from repro.kernels.tally import record_fallback
+from repro.kernels.tally import KernelDeclined, record_fallback
 
 from .count_a1 import A1State, DEFAULT_LCAP, count_a1 as _count_a1
 from .mapconcat import (
@@ -56,7 +56,7 @@ def _mapc_kernel_available() -> bool:
             from repro.kernels import ops as kops
             kops.kernel_mode()
             _PROBE_CACHE = True
-        except (ImportError, NotImplementedError):
+        except KernelDeclined:
             record_fallback("hybrid_mapc_probe")
             _PROBE_CACHE = False
     return _PROBE_CACHE

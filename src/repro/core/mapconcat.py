@@ -51,7 +51,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.tally import record_fallback
+from repro.kernels.tally import KernelDeclined, record_fallback
 
 from .count_a1 import DEFAULT_LCAP, count_a1 as _count_a1_exact, \
     dup_flags, step_bounded_list
@@ -347,10 +347,9 @@ def mapconcatenate_sharded(stream: EventStream, eps: EpisodeBatch,
         out = jnp.stack([a, c, b, ovf.astype(jnp.int32)])[None]  # [1,4,K,M]
         return jax.lax.all_gather(out, axis, axis=0, tiled=True)
 
-    from jax.experimental.shard_map import shard_map
-    fn = shard_map(map_step, mesh=mesh,
-                   in_specs=(P(axis), P(axis), P(axis)),
-                   out_specs=P(None), check_rep=False)
+    fn = jax.shard_map(map_step, mesh=mesh,
+                       in_specs=(P(axis), P(axis), P(axis)),
+                       out_specs=P(None), check_vma=False)
     gathered = np.asarray(jax.jit(fn)(
         jnp.asarray(wt), jnp.asarray(wtt), jnp.asarray(taus)))  # [P,4,K,M]
     a, c, b, ovf = (jnp.asarray(gathered[:, i]) for i in range(4))
@@ -431,7 +430,7 @@ def mapconcatenate_sharded_kernel(stream: EventStream, eps: EpisodeBatch,
         count, bad = kops.a1_mapconcat_sharded_count(
             stream, eps, num_segments=num_segments, lcap=lcap,
             num_devices=num_devices)
-    except (ImportError, NotImplementedError):
+    except KernelDeclined:
         record_fallback("mapc_sharded")
         d = shard_device_count() if num_devices is None else num_devices
         if d >= 2:
@@ -472,7 +471,7 @@ def mapconcatenate_kernel(stream: EventStream, eps: EpisodeBatch,
         count, bad = kops.a1_mapconcat_count(stream, eps,
                                              num_segments=num_segments,
                                              lcap=lcap)
-    except (ImportError, NotImplementedError):
+    except KernelDeclined:
         record_fallback("mapc_kernel")
         return mapconcatenate(stream, eps, num_segments=num_segments,
                               lcap=lcap, use_kernel=use_kernel)

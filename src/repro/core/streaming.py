@@ -95,7 +95,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.tally import record_fallback
+from repro.kernels.tally import KernelDeclined, record_fallback
 from repro.obs import span as _obs_span
 
 from . import candidates as _cand
@@ -254,7 +254,8 @@ class _Staged:
     """A window prepared for dispatch: holdback applied, history recorded,
     (ptpe) padded + transferred to device ahead of the blocking read."""
 
-    feed_types: object   # np.ndarray (mapc) or jax.Array (ptpe, padded)
+    feed_types: object   # np.ndarray (mapc), jax.Array (ptpe scan, padded)
+    #                      or list of fixed-width bricks (ptpe kernel)
     feed_times: object
     n: int               # real fed events
     final: bool
@@ -367,14 +368,17 @@ class StreamingCounter:
         try:
             from repro.kernels import ops as kops
             self._interp = kops.kernel_mode()
-        except (ImportError, NotImplementedError):
+        except KernelDeclined:
             record_fallback("stream_a1_residency")
             return
         self._kops = kops
         self._kernel = True
-        self._ket, self._ktlo, self._kthi = kops.episode_layout(
-            self.eps, inclusive_lower=False)
-        self._kst = kops.a1_state_layout(self._state)
+        # fixed-shape residency: lane blocks, each its own episode rows and
+        # state brick, fed fixed-width event chunks (ops.STREAM_BLOCK_M)
+        self._bm, self._be = kops.stream_block(self._interp)
+        self._keps = kops.lane_blocks(kops.episode_layout(
+            self.eps, inclusive_lower=False, block_m=self._bm), self._bm)
+        self._set_host_state(self._state)
         self._state = None  # authoritative state is the kernel brick now
 
     def _try_enable_mapc_kernel(self) -> None:
@@ -394,7 +398,7 @@ class StreamingCounter:
         try:
             from repro.kernels import ops as kops
             self._interp = kops.kernel_mode()
-        except (ImportError, NotImplementedError):
+        except KernelDeclined:
             record_fallback("stream_mapc_residency")
             return
         self._kops = kops
@@ -407,15 +411,16 @@ class StreamingCounter:
         """The carried machines in canonical episode-major layout (unpacks
         the kernel brick when the kernel path is resident)."""
         if self._kernel:
-            return self._kops.a1_state_unpack(*self._kst, self.eps.M,
-                                              self.eps.N)
+            return self._kops.a1_state_unpack(
+                *self._kops.join_lanes(self._kst), self.eps.M, self.eps.N)
         return self._state
 
     def _set_host_state(self, st: A1State) -> None:
         """Install canonical-layout machine state (repacks into the kernel
         brick when the kernel path is resident)."""
         if self._kernel:
-            self._kst = self._kops.a1_state_layout(st)
+            self._kst = self._kops.lane_blocks(self._kops.a1_state_layout(
+                st, block_m=self._bm), self._bm)
         else:
             self._state = st
 
@@ -464,14 +469,14 @@ class StreamingCounter:
             self._suffix.append((np.asarray(feed[0], np.int32).copy(),
                                  np.asarray(feed[1], np.int32).copy()))
         if self.engine == "ptpe" and n:
-            b = bucket_size(n, self.min_bucket)
             if self._kernel:
-                # kernel event brick (types; times; dup) — the per-chunk dup
-                # flags are exact because the tie-group holdback above
-                # guarantees the chunk never ends inside a tie group
-                ev = self._kops.event_brick(feed[0], feed[1], with_dup=True,
-                                            length=b)
-                return _Staged(jax.device_put(ev), None, n, final)
+                # fixed-width kernel event bricks (types; times; dup) — the
+                # dup flags are exact because the tie-group holdback above
+                # guarantees the feed never ends inside a tie group
+                ev = self._kops.event_chunks(feed[0], feed[1], with_dup=True,
+                                             width=self._be)
+                return _Staged(ev, None, n, final)
+            b = bucket_size(n, self.min_bucket)
             ft = np.full(b, PAD_TYPE, np.int32)
             ftt = np.full(b, feed[1][-1], np.int32)
             ft[:n] = feed[0]
@@ -492,19 +497,21 @@ class StreamingCounter:
         if self.engine == "ptpe":
             if staged.n:
                 if self._kernel:
-                    s, po, c, ovf = self._kst
-                    args = (self._ket, self._ktlo, self._kthi,
-                            staged.feed_types, s, po, c, ovf)
-                    if self.executor is not None:
-                        out = self.executor.a1_kernel_scan(
-                            args, self.eps.N, self.lcap, self._interp)
-                    else:
-                        with _obs_span("stream.launch", kind="a1_state"):
-                            out = self._kops.a1_state_call(
-                                *args, n_levels=self.eps.N, lcap=self.lcap,
-                                interpret=self._interp)
-                    c, ovf, s, po = out
-                    self._kst = (s, po, c, ovf)
+                    for b, ep_rows in enumerate(self._keps):
+                        for ev in staged.feed_types:
+                            args = ep_rows + (ev,) + self._kst[b]
+                            if self.executor is not None:
+                                out = self.executor.a1_kernel_scan(
+                                    args, self.eps.N, self.lcap, self._interp)
+                            else:
+                                with _obs_span("stream.launch",
+                                               kind="a1_state"):
+                                    out = self._kops.a1_state_call(
+                                        *args, n_levels=self.eps.N,
+                                        lcap=self.lcap,
+                                        interpret=self._interp)
+                            c, ovf, s, po = out
+                            self._kst[b] = (s, po, c, ovf)
                 else:
                     st = self._state
                     args = (self._et, self._tlo, self._thi,
@@ -582,7 +589,7 @@ class StreamingCounter:
         use_kernel = self._mapc_kernel
         if use_kernel and lw > self._kops.MAX_SEG_BRICK_LW:
             # the padded window brick would exceed segment_bricks'
-            # VMEM admission bound; run this commit on the XLA engine
+            # admission bound; run this commit on the XLA engine
             # (bit-identical carry — residency resumes next commit)
             record_fallback("stream_mapc_brick")
             use_kernel = False
@@ -657,8 +664,11 @@ class StreamingCounter:
             return self._cum.copy()
         if self.engine == "ptpe":
             if self._kernel:
-                c = np.asarray(self._kst[2][0, : self.eps.M], np.int64)
-                flagged = np.asarray(self._kst[3][0, : self.eps.M] != 0)
+                m = self.eps.M
+                c = np.concatenate([np.asarray(b[2])[0] for b in self._kst]
+                                   )[:m].astype(np.int64)
+                flagged = np.concatenate(
+                    [np.asarray(b[3])[0] for b in self._kst])[:m] != 0
             else:
                 c = np.asarray(self._state.count, np.int64)
                 flagged = np.asarray(self._state.ovf).copy()
@@ -1043,27 +1053,33 @@ class StreamingA2Counter:
         try:
             from repro.kernels import ops as kops
             self._interp = kops.kernel_mode()
-        except (ImportError, NotImplementedError):
+        except KernelDeclined:
             record_fallback("stream_a2_residency")
             return
         self._kops = kops
         self._kernel = True
-        self._ket, self._ktlo, self._kthi = kops.episode_layout(
-            self._relaxed, inclusive_lower=True)
-        self._kst = kops.a2_state_layout(self._state)
+        self._bm, self._be = kops.stream_block(self._interp)
+        self._keps = kops.lane_blocks(kops.episode_layout(
+            self._relaxed, inclusive_lower=True, block_m=self._bm), self._bm)
+        self._set_host_state(self._state)
         self._state = None
 
     def _host_state(self) -> A2State:
         if self._kernel:
-            return self._kops.a2_state_unpack(*self._kst, self.eps.M,
-                                              self.eps.N)
+            return self._kops.a2_state_unpack(
+                *self._kops.join_lanes(self._kst), self.eps.M, self.eps.N)
         return self._state
 
     def _set_host_state(self, st: A2State) -> None:
         if self._kernel:
-            self._kst = self._kops.a2_state_layout(st)
+            self._kst = self._kops.lane_blocks(self._kops.a2_state_layout(
+                st, block_m=self._bm), self._bm)
         else:
             self._state = st
+
+    def _kernel_counts(self) -> np.ndarray:
+        return np.concatenate([np.asarray(b[1])[0] for b in self._kst]
+                              )[: self.eps.M].astype(np.int64)
 
     def update(self, window: EventStream, final: bool = False) -> np.ndarray:
         real = window.types != PAD_TYPE
@@ -1073,26 +1089,25 @@ class StreamingA2Counter:
                 self._cum += count_level1(window, self.eps.etypes[:, 0])
             out = self._cum.copy()
         elif n == 0:
-            out = (np.asarray(self._kst[1][0, : self.eps.M], np.int64)
-                   if self._kernel
+            out = (self._kernel_counts() if self._kernel
                    else np.asarray(self._state.count, np.int64))
         elif self._kernel:
-            b = bucket_size(n, self.min_bucket)
-            ev = self._kops.event_brick(window.types[real],
-                                        window.times[real],
-                                        with_dup=False, length=b)
-            s, c = self._kst
-            args = (self._ket, self._ktlo, self._kthi,
-                    jax.device_put(ev), s, c)
-            if self.executor is not None:
-                c, s = self.executor.a2_kernel_scan(args, self.eps.N,
-                                                    self._interp)
-            else:
-                with _obs_span("stream.launch", kind="a2_state"):
-                    c, s = self._kops.a2_state_call(
-                        *args, n_levels=self.eps.N, interpret=self._interp)
-            self._kst = (s, c)
-            out = np.asarray(c[0, : self.eps.M], np.int64)
+            chunks = self._kops.event_chunks(window.types[real],
+                                             window.times[real],
+                                             with_dup=False, width=self._be)
+            for b, ep_rows in enumerate(self._keps):
+                for ev in chunks:
+                    args = ep_rows + (ev,) + self._kst[b]
+                    if self.executor is not None:
+                        c, s = self.executor.a2_kernel_scan(
+                            args, self.eps.N, self._interp)
+                    else:
+                        with _obs_span("stream.launch", kind="a2_state"):
+                            c, s = self._kops.a2_state_call(
+                                *args, n_levels=self.eps.N,
+                                interpret=self._interp)
+                    self._kst[b] = (s, c)
+            out = self._kernel_counts()
         else:
             sub = EventStream(window.types[real], window.times[real],
                               window.num_types)
@@ -1242,8 +1257,15 @@ class StreamingMiner:
                 eps.thi.tobytes())
 
     def _make_counter(self, eps: EpisodeBatch) -> StreamingCounter:
+        # An episode's per-window deltas come from a different candidate
+        # batch's counter from one window to the next; they sum to the
+        # exact total only if every counter attributes completions at the
+        # same frontier. The per-batch hybrid choice would mix PTPE (tie-
+        # group frontier) with MapConcatenate (W ticks behind), so the
+        # miner resolves "hybrid" to PTPE for all of its counters.
+        engine = "ptpe" if self.engine == "hybrid" else self.engine
         return StreamingCounter(
-            eps, engine=self.engine, lcap=self.lcap,
+            eps, engine=engine, lcap=self.lcap,
             num_segments=self.num_segments, use_kernel=self.use_kernel,
             executor=self.executor, checkpoint_interval=self.history_limit)
 

@@ -31,11 +31,17 @@ Modules:
 
 Event streaming: the stream is never broadcast whole. Event bricks are
 blocked on the second grid axis (``block_e`` events per step, default
-``DEFAULT_BLOCK_E``) and DMA'd/double-buffered per step while the machine
-state lives in output blocks revisited across the axis — fresh-state and
-state-carried wrappers share the same chunked launch, so VMEM bounds the
-*chunk*, not the stream. Segmented kernels block by time segment instead
-(one (types/times/dup/τ_p/τ_{p+1}) brick per step).
+``DEFAULT_BLOCK_E``) and copied per step into **SMEM**, where the kernels
+read each event as a scalar (Mosaic has no scalar load from VMEM at a
+dynamic lane), while the machine state lives in output blocks revisited
+across the axis — fresh-state and state-carried wrappers share the same
+chunked launch. Segmented kernels block by time segment and, within a
+segment, by event chunk (a third grid axis over the
+(types/times/dup/τ_p/τ_{p+1}) brick), with the phase machines carried
+across chunks in VMEM scratch. Inside every kernel, state is held as
+(NP, BM) rows — the level edge gate is a sublane rotate plus an iota
+select and the last-level row a static slice — since Mosaic refuses
+scatters (``.at[].set``) and boolean sublane concatenation.
 
 Layout contract for the carried state (see ``ops``): episode-major host
 state (``core.count_a1.A1State`` [M, N, L] / ``core.count_a2.A2State``

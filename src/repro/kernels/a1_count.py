@@ -3,11 +3,13 @@
 Same computation-to-core mapping as ``a2_count`` (episodes on lanes, levels
 on sublanes, events chunked on an ``arbitrary`` second grid axis with the
 machine state carried in the revisited output blocks) plus a bounded witness
-list per level: state is an (NP, LCAP, BM) timestamp brick. The paper's
-data-dependent list walk becomes a masked reduction over the LCAP axis; the
-circular write pointer is kept as a one-hot (NP, LCAP, BM) mask rotated on
-append — no gathers, no scatters, pure VPU ops (this is the TPU answer to
-the divergence/local-memory costs the paper profiles in Fig. 10).
+list per level: state is an (NP, LCAP, BM) timestamp brick with a one-hot
+(NP, LCAP, BM) write-pointer mask. Inside the kernel the brick is held as
+LCAP (NP, BM) slot rows and the mask as a slot index, so the paper's
+data-dependent list walk becomes an OR/select over the slot rows and the
+circular append a pointer increment — no gathers, no scatters, pure VPU
+ops on whole vregs (this is the TPU answer to the divergence/local-memory
+costs the paper profiles in Fig. 10).
 
 Outputs: counts AND a live-eviction flag per episode (see
 core/count_a1.py — flagged episodes are recounted exactly by the host).
@@ -28,8 +30,9 @@ between this brick layout and ``core.count_a1.A1State``'s episode-major
 [M, N, L] arrays) lives in ``ops.a1_state_layout`` / ``a1_state_unpack``.
 
 Segment-parallel variant (``a1_mapconcat_kernel``): MapConcatenate
-(§5.2.2) on-chip — grid = (episode tile × time segment); each segment runs
-K = N phase-shifted bounded-list machines and emits the (a, count, b)
+(§5.2.2) on-chip — grid = (episode tile × time segment × event chunk);
+each segment runs K = N phase-shifted bounded-list machines (carried
+across its chunks in VMEM scratch) and emits the (a, count, b)
 tuple (Fig. 5), with the Concatenate stage fused into the launch (the
 stitched tuple carries in revisited output blocks, folded per segment via
 ``core.mapconcat.fold_pair_unrolled``; the ``unmatched`` flag and the
@@ -46,48 +49,61 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.events import PAD_TYPE, TIME_NEG_INF
+from repro.core.events import TIME_NEG_INF
 from repro.core.mapconcat import stitch_zones
 
-from .a2_count import (DEFAULT_BLOCK_E, LANES, SEG_DUP,
-                       SEG_ROWS, SEG_TAU_HI, SEG_TAU_LO, SEG_TIME, SEG_TYPE,
-                       SEQ_GRID, SUBLANES, _block_e, _mapc_fold_and_emit)
+from .a2_count import (DEFAULT_BLOCK_E, LANES, ROW_OVF, SEG_DUP, SEG_TIME,
+                       SEG_TYPE, SEQ_GRID, SUBLANES, _block_e, _gate_phase,
+                       _mapc_begin, _mapc_end, _prev_level, mapc_call)
 
 
-def _a1_body(n_levels: int, et, tlo, thi, ev_ref):
-    """Per-event step over the (s, po, cnt, ovf) carry — shared by the
-    fresh-state and state-carried kernels."""
-    np_, bm = et.shape
+def _a1_step(n_levels: int, lcap: int, et, tlo, thi, level, e, t, dup,
+             s, ptr):
+    """One event through the bounded-list machines of one episode tile.
+
+    ``s`` is a tuple of ``lcap`` (NP, BM) slot rows and ``ptr`` the
+    (NP, BM) circular write pointer. Returns the advanced (s, ptr), the
+    (1, BM) completion row and the (NP, BM) live-eviction mask — shared by
+    the carried and the segmented kernels."""
+    last = n_levels - 1
+    ok = functools.reduce(
+        jnp.logical_or, [(t - sl > tlo) & (t - sl <= thi) for sl in s])
+    advance = (et == e) & _prev_level(ok, level)            # (NP, BM)
+    complete = advance[last:last + 1, :]                    # (1, BM)
+    store = advance & (level != last) & ~complete
+    # live-eviction: evicted witness may still have a same-tick or
+    # lower-bounded consumer (see core/count_a1.py docstring)
+    v = jnp.full(et.shape, TIME_NEG_INF, jnp.int32)
+    for slot, sl in enumerate(s):
+        v = jnp.where(store & (ptr == slot), sl, v)
+    live = (v > TIME_NEG_INF) & (t - v <= thi) & ((tlo > 0) | dup)
+    s = tuple(jnp.where(complete, TIME_NEG_INF,
+                        jnp.where(store & (ptr == slot), t, sl))
+              for slot, sl in enumerate(s))
+    ptr = jnp.where(store, jnp.where(ptr == lcap - 1, 0, ptr + 1), ptr)
+    ptr = jnp.where(complete, 0, ptr)
+    return s, ptr, complete, live
+
+
+def _any_level(x):
+    """(NP, BM) bool → (1, BM) i32: whether any level row is set."""
+    return jnp.max(x.astype(jnp.int32), axis=0, keepdims=True)
+
+
+def _a1_body(n_levels: int, lcap: int, et, tlo, thi, ev_ref):
+    """Per-event step over the (s, ptr, cnt, ovf) carry of the carried
+    kernel; the events are read as scalars from the SMEM chunk."""
+    level = jax.lax.broadcasted_iota(jnp.int32, et.shape, 0)
 
     def body(j, carry):
-        s, po, cnt, ovf = carry  # s,(NP,L,BM) po one-hot,(NP,L,BM)
-        e = ev_ref[0, j]
-        t = ev_ref[1, j]
-        dup = ev_ref[2, j] != 0
-        match = et == e                                     # (NP, BM)
-        delta = t - s                                       # (NP, L, BM)
-        witness = (delta > tlo[:, None, :]) & (delta <= thi[:, None, :])
-        ok = witness.any(axis=1)                            # (NP, BM) row i =
-        ok_shift = jnp.concatenate(                         # edge i→i+1 holds
-            [jnp.ones((1, bm), jnp.bool_), ok[:-1, :]], axis=0)
-        advance = match & ok_shift
-        complete = advance[n_levels - 1, :]                 # (BM,)
-        store = advance.at[n_levels - 1, :].set(False)
-        store = store & ~complete[None, :]
-        write = store[:, None, :] & po                      # (NP, L, BM)
-        # live-eviction: evicted witness may still have a same-tick or
-        # lower-bounded consumer (see core/count_a1.py docstring)
-        v = jnp.where(write, s, TIME_NEG_INF).max(axis=1)   # (NP, BM)
-        live = (v > TIME_NEG_INF) & (t - v <= thi) & ((tlo > 0) | dup)
-        ovf = ovf | live.any(axis=0)[None, :].astype(jnp.int32)
-        s = jnp.where(write, t, s)
-        po = jnp.where(store[:, None, :], jnp.roll(po, 1, axis=1), po)
-        s = jnp.where(complete[None, None, :], TIME_NEG_INF, s)
-        po0 = jnp.zeros_like(po).at[:, 0, :].set(True)
-        po = jnp.where(complete[None, None, :], po0, po)
-        cnt = cnt + complete.astype(jnp.int32)[None, :]
-        return s, po, cnt, ovf
+        s, ptr, cnt, ovf = carry
+        s, ptr, complete, live = _a1_step(
+            n_levels, lcap, et, tlo, thi, level, ev_ref[0, j],
+            ev_ref[1, j], ev_ref[2, j] != 0, s, ptr)
+        return (s, ptr, cnt + complete.astype(jnp.int32),
+                ovf | _any_level(live))
 
     return body
 
@@ -97,7 +113,9 @@ def _a1_state_kernel(n_levels: int, lcap: int, et_ref, tlo_ref, thi_ref,
                      cnt_ref, ovf_ref, sout_ref, poout_ref):
     """One (episode tile × event chunk) grid step: resume the machines from
     the carried output blocks (seeded from the state inputs at chunk 0) and
-    advance them past this chunk's events."""
+    advance them past this chunk's events. Inside the kernel the
+    (NP, LCAP, BM) bricks are held as ``lcap`` (NP, BM) slot rows and the
+    one-hot pointer mask as a slot index."""
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -107,18 +125,20 @@ def _a1_state_kernel(n_levels: int, lcap: int, et_ref, tlo_ref, thi_ref,
         cnt_ref[...] = cin_ref[...]
         ovf_ref[...] = oin_ref[...]
 
-    et = et_ref[...]
-    tlo = tlo_ref[...]
-    thi = thi_ref[...]
-    body = _a1_body(n_levels, et, tlo, thi, ev_ref)
-    s, po, cnt, ovf = jax.lax.fori_loop(
+    s = tuple(sout_ref[:, slot, :] for slot in range(lcap))
+    ptr = jnp.zeros(s[0].shape, jnp.int32)
+    for slot in range(1, lcap):
+        ptr = jnp.where(poout_ref[:, slot, :] != 0, slot, ptr)
+    body = _a1_body(n_levels, lcap, et_ref[...], tlo_ref[...], thi_ref[...],
+                    ev_ref)
+    s, ptr, cnt, ovf = jax.lax.fori_loop(
         0, ev_ref.shape[1], body,
-        (sout_ref[...], poout_ref[...] != 0, cnt_ref[0:1, :],
-         ovf_ref[0:1, :]))
+        (s, ptr, cnt_ref[0:1, :], ovf_ref[0:1, :]))
     cnt_ref[...] = jnp.broadcast_to(cnt, cnt_ref.shape)
     ovf_ref[...] = jnp.broadcast_to(ovf, ovf_ref.shape)
-    sout_ref[...] = s
-    poout_ref[...] = po.astype(jnp.int32)
+    for slot in range(lcap):
+        sout_ref[:, slot, :] = s[slot]
+        poout_ref[:, slot, :] = (ptr == slot).astype(jnp.int32)
 
 
 @functools.partial(
@@ -185,7 +205,8 @@ def a1_count_state_kernel(etypes, tlo, thi, events, s, po, cnt, ovf, *,
             pl.BlockSpec((np_, block_m), tile),
             pl.BlockSpec((np_, block_m), tile),
             pl.BlockSpec((np_, block_m), tile),
-            pl.BlockSpec((events.shape[0], be), lambda i, j: (0, j)),
+            pl.BlockSpec((events.shape[0], be), lambda i, j: (0, j),
+                         memory_space=pltpu.SMEM),
             pl.BlockSpec((np_, lcap, block_m), tile3),
             pl.BlockSpec((np_, lcap, block_m), tile3),
             pl.BlockSpec((SUBLANES, block_m), tile),
@@ -207,90 +228,57 @@ def a1_count_state_kernel(etypes, tlo, thi, events, s, po, cnt, ovf, *,
 # --------------------------------------------------------------------------
 
 
-def _a1_mapc_body(n_levels: int, lcap: int, et, tlo, thi, starts, tau_lo,
-                  tau_hi, w_row, ev_ref):
-    """Per-event step for the K = N phase-shifted bounded-list machines of
-    one time segment (kernel analogue of ``core.mapconcat._segment_scan``'s
-    scan body; zone predicates shared via ``core.mapconcat.stitch_zones``).
-
-    Carry: s/po (K, NP, LCAP, BM); cnt/ovf/a/b/done/a_set (K, BM)."""
-    k = n_levels
-    np_, bm = et.shape
-
-    def body(j, carry):
-        s, po, cnt, ovf, a, b, done, a_set = carry
-        e = ev_ref[0, SEG_TYPE, j]
-        t = ev_ref[0, SEG_TIME, j]
-        dup = ev_ref[0, SEG_DUP, j] != 0
-        match = et == e                                       # (NP, BM)
-        delta = t - s                                         # (K,NP,L,BM)
-        witness = ((delta > tlo[None, :, None, :])
-                   & (delta <= thi[None, :, None, :]))
-        ok = witness.any(axis=2)                              # (K, NP, BM)
-        ok_shift = jnp.concatenate(
-            [jnp.ones((k, 1, bm), jnp.bool_), ok[:, :-1, :]], axis=1)
-        advance = match[None] & ok_shift                      # (K, NP, BM)
-        raw_complete = advance[:, n_levels - 1, :]            # (K, BM)
-        store = advance.at[:, n_levels - 1, :].set(False)
-        store = store & ~raw_complete[:, None, :]
-        write = store[:, :, None, :] & po                     # (K,NP,L,BM)
-        v = jnp.where(write, s, TIME_NEG_INF).max(axis=2)     # (K, NP, BM)
-        live_ev = ((v > TIME_NEG_INF) & (t - v <= thi[None])
-                   & ((tlo[None] > 0) | dup))
-        ovf2 = ovf | live_ev.any(axis=1)                      # (K, BM)
-        s2 = jnp.where(write, t, s)
-        po2 = jnp.where(store[:, :, None, :], jnp.roll(po, 1, axis=2), po)
-        s2 = jnp.where(raw_complete[:, None, None, :], TIME_NEG_INF, s2)
-        po_reset = jnp.zeros_like(po).at[:, :, 0, :].set(True)
-        po2 = jnp.where(raw_complete[:, None, None, :], po_reset, po2)
-        # zone gating (single source of truth: core.mapconcat.stitch_zones)
-        seg_z, a_z, live_z, cross_z = stitch_zones(t, tau_lo, tau_hi, w_row)
-        in_window = (t > starts) & live_z & ~done             # (K, BM)
-        live = in_window & (e != PAD_TYPE)
-        s = jnp.where(live[:, None, None, :], s2, s)
-        po = jnp.where(live[:, None, None, :], po2, po)
-        ovf = jnp.where(live, ovf2, ovf)
-        complete = raw_complete & in_window
-        in_seg = complete & seg_z
-        cnt = cnt + in_seg.astype(jnp.int32)
-        rec_a = in_seg & ~a_set & a_z
-        a = jnp.where(rec_a, t, a)
-        a_set = a_set | rec_a
-        crossing = complete & cross_z
-        b = jnp.where(crossing, t, b)
-        done = done | crossing
-        return s, po, cnt, ovf, a, b, done, a_set
-
-    return body
-
-
 def _a1_mapc_kernel(n_levels: int, lcap: int, et_ref, tlo_ref, thi_ref,
                     cum_ref, w_ref, ev_ref, a_ref, c_ref, b_ref, f_ref,
-                    ovf_ref):
-    """One (episode tile × time segment) grid step: Map this segment with
-    K phase-shifted bounded-list machines, then fold its tuple onto the
-    carried Concatenate state (revisited output blocks)."""
+                    ovf_ref, s_ref, ptr_ref, rows_ref):
+    """One (episode tile × time segment × event chunk) grid step: advance
+    the segment's K = N phase-shifted bounded-list machines (slot rows
+    ``s_ref`` (K·LCAP, NP, BM) and pointers ``ptr_ref`` (K, NP, BM)
+    carried across chunks in VMEM scratch) past this chunk, and fold the
+    segment's tuple onto the carried Concatenate state after its last
+    chunk (kernel analogue of ``core.mapconcat._segment_scan``'s scan
+    body)."""
+    k = n_levels
+
+    def reset_state():
+        s_ref[...] = jnp.full(s_ref.shape, TIME_NEG_INF, jnp.int32)
+        ptr_ref[...] = jnp.zeros(ptr_ref.shape, jnp.int32)
+
+    tau_lo, tau_hi, starts, rows = _mapc_begin(k, cum_ref, ev_ref, rows_ref,
+                                               reset_state)
     et = et_ref[...]
     tlo = tlo_ref[...]
     thi = thi_ref[...]
-    np_, bm = et.shape
-    k = n_levels
-    tau_lo = ev_ref[0, SEG_TAU_LO, 0]
-    tau_hi = ev_ref[0, SEG_TAU_HI, 0]
-    w_row = w_ref[0, :]                        # (BM,) per-episode max span
-    starts = tau_lo - cum_ref[...][:k]         # (K, BM) phase start times
-    body = _a1_mapc_body(n_levels, lcap, et, tlo, thi, starts, tau_lo,
-                         tau_hi, w_row, ev_ref)
-    s0 = jnp.full((k, np_, lcap, bm), TIME_NEG_INF, jnp.int32)
-    po0 = jnp.zeros((k, np_, lcap, bm), jnp.bool_).at[:, :, 0, :].set(True)
-    zi = jnp.zeros((k, bm), jnp.int32)
-    zb = jnp.zeros((k, bm), jnp.bool_)
-    a0 = jnp.full((k, bm), tau_lo, jnp.int32)
-    b0 = jnp.full((k, bm), tau_hi, jnp.int32)
-    _, _, cnt, ovf, a, b, _, _ = jax.lax.fori_loop(
-        0, ev_ref.shape[2], body, (s0, po0, zi, zb, a0, b0, zb, zb))
-    _mapc_fold_and_emit(n_levels, (a, cnt, b), ovf.any(axis=0),
-                        a_ref, c_ref, b_ref, f_ref, ovf_ref)
+    w_row = w_ref[0:1, :]                     # per-episode max span
+    level = jax.lax.broadcasted_iota(jnp.int32, et.shape, 0)
+
+    def body(j, phases):
+        e = ev_ref[0, SEG_TYPE, j]
+        t = ev_ref[0, SEG_TIME, j]
+        dup = ev_ref[0, SEG_DUP, j] != 0
+        zones = stitch_zones(t, tau_lo, tau_hi, w_row)
+        out = []
+        for start, (s, ptr, rows) in zip(starts, phases):
+            s2, ptr2, raw_complete, live_ev = _a1_step(
+                n_levels, lcap, et, tlo, thi, level, e, t, dup, s, ptr)
+            live, rows = _gate_phase(t, e, start, zones, raw_complete, rows)
+            ovf = rows[ROW_OVF] | (live & (_any_level(live_ev) != 0)
+                                   ).astype(jnp.int32)
+            out.append((tuple(jnp.where(live, x2, x)
+                              for x2, x in zip(s2, s)),
+                        jnp.where(live, ptr2, ptr),
+                        rows[:ROW_OVF] + (ovf,)))
+        return tuple(out)
+
+    init = tuple((tuple(s_ref[kk * lcap + slot] for slot in range(lcap)),
+                  ptr_ref[kk], rows[kk]) for kk in range(k))
+    phases = jax.lax.fori_loop(0, ev_ref.shape[2], body, init)
+    for kk, (s, ptr, _) in enumerate(phases):
+        for slot in range(lcap):
+            s_ref[kk * lcap + slot] = s[slot]
+        ptr_ref[kk] = ptr
+    _mapc_end(k, [r for _, _, r in phases], rows_ref, a_ref, c_ref, b_ref,
+              f_ref, ovf_ref)
 
 
 @functools.partial(
@@ -299,7 +287,7 @@ def a1_mapconcat_kernel(etypes, tlo, thi, cum, w, segs, *, n_levels: int,
                         lcap: int = 4, block_m: int = LANES,
                         interpret: bool = False):
     """Segment-parallel bounded-list pallas_call: grid = (episode tile ×
-    time segment), Map + fused Concatenate in one launch.
+    time segment × event chunk), Map + fused Concatenate in one launch.
 
     Args as ``a2_mapconcat_kernel`` (``tlo`` unshifted — A1 keeps the
     strict lower bound). Returns (a, c, b, f) each i32[NP, M] — the
@@ -308,28 +296,6 @@ def a1_mapconcat_kernel(etypes, tlo, thi, cum, w, segs, *, n_levels: int,
     Row 0 of ``c`` is the count; an episode needs the host's exact
     fallback iff ``f[0] | ovf[0]``.
     """
-    np_, m = etypes.shape
-    p = segs.shape[0]
-    grid = (m // block_m, p)
-    kernel = functools.partial(_a1_mapc_kernel, n_levels, lcap)
-    tile = lambda i, j: (0, i)  # noqa: E731
-    out_shape = ([jax.ShapeDtypeStruct((np_, m), jnp.int32)] * 4
-                 + [jax.ShapeDtypeStruct((SUBLANES, m), jnp.int32)])
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((np_, block_m), tile),
-            pl.BlockSpec((np_, block_m), tile),
-            pl.BlockSpec((np_, block_m), tile),
-            pl.BlockSpec((np_, block_m), tile),
-            pl.BlockSpec((SUBLANES, block_m), tile),
-            pl.BlockSpec((1, SEG_ROWS, segs.shape[2]),
-                         lambda i, j: (j, 0, 0)),
-        ],
-        out_specs=([pl.BlockSpec((np_, block_m), tile)] * 4
-                   + [pl.BlockSpec((SUBLANES, block_m), tile)]),
-        out_shape=out_shape,
-        compiler_params=SEQ_GRID,
-        interpret=interpret,
-    )(etypes, tlo, thi, cum, w, segs)
+    return mapc_call(functools.partial(_a1_mapc_kernel, n_levels, lcap),
+                     [(n_levels * lcap,), (n_levels,)], etypes, tlo, thi,
+                     cum, w, segs, block_m=block_m, interpret=interpret)

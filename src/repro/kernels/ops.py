@@ -27,8 +27,9 @@ Dispatch policy:
   * anywhere with ``REPRO_INTERPRET_KERNELS=1`` / ``REPRO_KERNEL_INTERPRET=1``
     (or ``force="interpret"``) — ``interpret=True`` (kernel body executed by
     XLA CPU; used by tests and the CI kernel job);
-  * otherwise — raise NotImplementedError so callers (core/count_*.py) fall
-    back to the XLA-scan engine, which is the fast CPU path.
+  * otherwise — raise ``KernelDeclined`` so callers (core/count_*.py) fall
+    back to the XLA-scan engine, which is the fast CPU path. Only this
+    decline reroutes: a lowering or compile error of a kernel propagates.
 
 ``KERNEL_CALLS`` tallies host-side kernel dispatches per kind ("a1", "a2",
 "a1_state", "a2_state", "a1_mapc", "a2_mapc", and the per-device
@@ -63,21 +64,21 @@ from .a2_count import (DEFAULT_BLOCK_E, LANES, PAD_ROW_TYPE, SEG_ROWS,
                        a2_mapconcat_kernel)
 from repro.obs.jaxprof import annotate
 
-from .tally import KERNEL_CALLS, interpret_requested
+from .tally import KERNEL_CALLS, KernelDeclined, interpret_requested
 from .tally import record_fallback, reset_kernel_calls  # noqa: F401
 
 # Largest per-segment event-window length (LW) the segmented-kernel
-# dispatch admits. The segment brick is DMA'd whole per grid step —
-# 5 rows × LW × 4 bytes, double-buffered — so an unbounded LW can blow
-# the VMEM budget with a runtime crash as the only signal. Beyond this
-# the dispatch declines (NotImplementedError) and callers take the XLA
-# MapConcatenate, which has no VMEM ceiling; the admitted value is
-# validated against the budget by ``repro.analysis.vmem``.
+# dispatch admits. The kernels stream each window through SMEM in
+# DEFAULT_BLOCK_E-event chunks, so LW does not bound VMEM; this caps the
+# per-launch brick (P × 5 × LW i32 packed on the host and copied to HBM)
+# at the corner ``tests/test_tpu_compile.py`` compiles for v5e. Beyond it
+# the dispatch declines (KernelDeclined) and callers take the XLA
+# MapConcatenate; ``repro.analysis.vmem`` holds it to the chunk quantum.
 MAX_SEG_BRICK_LW = 1 << 17
 
 
 def _mode(force: str | None) -> bool:
-    """Returns interpret flag, or raises NotImplementedError to decline."""
+    """Returns interpret flag, or raises KernelDeclined to decline."""
     if force == "compiled":
         return False
     if force == "interpret":
@@ -86,12 +87,12 @@ def _mode(force: str | None) -> bool:
         return False
     if interpret_requested():
         return True
-    raise NotImplementedError("no TPU and interpret mode not requested")
+    raise KernelDeclined("no TPU and interpret mode not requested")
 
 
 def kernel_mode(force: str | None = None) -> bool:
     """Public dispatch probe: the interpret flag the kernels should run
-    with, or NotImplementedError when the caller should use the XLA-scan
+    with, or KernelDeclined when the caller should use the XLA-scan
     engine instead. Streaming counters probe once at construction."""
     return _mode(force)
 
@@ -116,12 +117,7 @@ def episode_layout(eps: EpisodeBatch, inclusive_lower: bool,
     return jnp.asarray(et), jnp.asarray(tlo), jnp.asarray(thi)
 
 
-def event_brick(types, times, with_dup: bool, length: int | None = None):
-    """Raw event arrays → padded i32[2 or 3, EP] kernel brick
-    (types; times; [dup]). ``length`` overrides the default padding
-    (streaming uses its shape buckets): round-up-to-128, and for streams
-    longer than one event chunk round-up-to-``DEFAULT_BLOCK_E`` so the
-    kernels' chunked event ``BlockSpec`` divides the brick evenly."""
+def _event_rows(types, times, with_dup: bool, length: int | None):
     types = np.asarray(types, np.int32)
     times = np.asarray(times, np.int32)
     n = types.shape[0]
@@ -141,7 +137,15 @@ def event_brick(types, times, with_dup: bool, length: int | None = None):
     if with_dup and n > 1:
         ev[2, : n - 1] = ((times[1:] == times[:-1])
                           & (types[1:] != PAD_TYPE)).astype(np.int32)
-    return jnp.asarray(ev)
+    return ev
+
+
+def event_brick(types, times, with_dup: bool):
+    """Raw event arrays → padded i32[2 or 3, EP] kernel brick
+    (types; times; [dup]): EP rounds up to 128, and for streams longer
+    than one event chunk to ``DEFAULT_BLOCK_E`` so the kernels' chunked
+    event ``BlockSpec`` divides the brick evenly."""
+    return jnp.asarray(_event_rows(types, times, with_dup, None))
 
 
 def event_layout(stream: EventStream, with_dup: bool):
@@ -268,6 +272,48 @@ def a2_state_call(et, tlo, thi, ev, s, cnt, *, n_levels: int,
                                      n_levels=n_levels, interpret=interpret)
 
 
+# Fixed launch shape of the carried kernels on the streaming path. A
+# served session's candidate batches change size every window, and its
+# windows change length; launching every carried chunk as
+# STREAM_BLOCK_M episode lanes × STREAM_BLOCK_E events (lane blocks and
+# event chunks of one counter are separate launches) leaves one compiled
+# kernel per level N, whatever the batch and window sizes. Interpret mode
+# (the CPU tests) runs the same blocking at one lane tile × 128 events:
+# its cost grows with the padded shape and it compiles nothing for a chip.
+STREAM_BLOCK_M = 512
+STREAM_BLOCK_E = 2048
+
+
+def stream_block(interpret: bool) -> tuple[int, int]:
+    """(episode lanes, events) of one carried streaming launch."""
+    return (LANES, LANES) if interpret else (STREAM_BLOCK_M, STREAM_BLOCK_E)
+
+
+def lane_blocks(arrays, width: int) -> list[tuple]:
+    """Split kernel-layout arrays (lane axis last, a multiple of
+    ``width``) into per-block tuples of ``width``-lane device arrays."""
+    host = [np.asarray(a) for a in arrays]
+    return [tuple(jnp.asarray(a[..., i: i + width]) for a in host)
+            for i in range(0, host[0].shape[-1], width)]
+
+
+def join_lanes(blocks: list[tuple]) -> tuple:
+    """Inverse of ``lane_blocks``: host arrays with the blocks' lanes
+    concatenated."""
+    return tuple(np.concatenate([np.asarray(b[k]) for b in blocks], axis=-1)
+                 for k in range(len(blocks[0])))
+
+
+def event_chunks(types, times, with_dup: bool, width: int) -> list:
+    """Events → ``width``-event device bricks, the last one padded. The
+    dup row is computed over the whole feed, so carrying the machines
+    through the chunks equals one launch over the concatenation."""
+    n = int(np.shape(types)[0])
+    ev = _event_rows(types, times, with_dup, _round_up(max(n, 1), width))
+    return [jax.device_put(ev[:, i: i + width])
+            for i in range(0, ev.shape[1], width)]
+
+
 # --------------------------------------------------------------------------
 # Segment-parallel (MapConcatenate) dispatch: layout + instrumented calls
 # --------------------------------------------------------------------------
@@ -300,21 +346,28 @@ def segment_bricks(wt, wtt, tau, length: int | None = None):
 
     Rows: (types, times, dup, τ_p, τ_{p+1}) — the boundary rows are
     broadcast along the window (the kernel reads them as scalars at column
-    0). ``dup`` marks a same-timestamp real successor *within the window*,
+    0 of every chunk). ``dup`` marks a same-timestamp real successor *within the window*,
     matching the per-window ``core.count_a1.dup_flags`` semantics the XLA
     Map step uses. ``length`` overrides the round-up-to-128 window padding
-    (the cross-session batcher re-buckets to the fused group's max).
+    (the cross-session batcher re-buckets to the fused group's max);
+    past one chunk the default pads to a ``DEFAULT_BLOCK_E`` multiple so
+    the kernels' chunked SMEM ``BlockSpec`` divides the window.
     """
     wt = np.asarray(wt, np.int32)
     wtt = np.asarray(wtt, np.int32)
     p, lw = wt.shape
-    lwp = _round_up(max(lw, 1), LANES) if length is None else length
+    if length is None:
+        lwp = _round_up(max(lw, 1), LANES)
+        if lwp > DEFAULT_BLOCK_E:
+            lwp = _round_up(lwp, DEFAULT_BLOCK_E)
+    else:
+        lwp = length
     if lwp > MAX_SEG_BRICK_LW:
-        # an unadmitted brick would overflow VMEM at launch; decline so
-        # the caller's graceful-degradation path takes the XLA engine
-        raise NotImplementedError(
+        # beyond the admitted corner; decline so the caller's
+        # graceful-degradation path takes the XLA engine
+        raise KernelDeclined(
             f"segment brick LW={lwp} exceeds the admitted "
-            f"MAX_SEG_BRICK_LW={MAX_SEG_BRICK_LW} (VMEM budget)")
+            f"MAX_SEG_BRICK_LW={MAX_SEG_BRICK_LW}")
     ev = np.zeros((p, SEG_ROWS, lwp), np.int32)
     ev[:, 0, :] = PAD_TYPE
     ev[:, 0, :lw] = wt
@@ -424,8 +477,16 @@ def _stream_mesh(d: int):
 @functools.lru_cache(maxsize=None)
 def _mapc_sharded_fn(kind: str, n_levels: int, lcap: int, interpret: bool,
                      d: int, lanes: bool):
-    """Build (and cache) the sharded segmented launch: a ``shard_map`` over
-    the mesh ``data`` axis where each device runs ONE segmented Pallas
+    """The sharded segmented launch over the first ``d`` devices (cached
+    per shape key); see ``mapc_sharded_launch``."""
+    return mapc_sharded_launch(_stream_mesh(d), kind, n_levels, lcap,
+                               interpret, lanes)
+
+
+def mapc_sharded_launch(mesh, kind: str, n_levels: int, lcap: int,
+                        interpret: bool, lanes: bool):
+    """Build the sharded segmented launch: a ``shard_map`` over the
+    ``mesh``'s ``data`` axis where each device runs ONE segmented Pallas
     launch on its contiguous segment group (grid = episode tile × local
     segments, in-group Concatenate fused on-chip), then all-gathers the
     O(P·N) per-device (a, count, b, f) tuples and folds them replicated —
@@ -437,12 +498,11 @@ def _mapc_sharded_fn(kind: str, n_levels: int, lcap: int, interpret: bool,
     segment axis shards over devices. Returns a jitted callable with the
     same (a, c, b, f, ovf) output contract as ``a1_mapconcat_kernel``.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.mapconcat import fold_pair
 
-    mesh = _stream_mesh(d)
+    d = mesh.shape["data"]
     if kind == "a1":
         base = functools.partial(a1_mapconcat_kernel, n_levels=n_levels,
                                  lcap=lcap, interpret=interpret)
@@ -484,8 +544,8 @@ def _mapc_sharded_fn(kind: str, n_levels: int, lcap: int, interpret: bool,
 
     seg_spec = P(None, "data") if lanes else P("data")
     in_specs = (P(), P(), P(), P(), P(), seg_spec)
-    return jax.jit(shard_map(dev_fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=(P(),) * 5, check_rep=False))
+    return jax.jit(jax.shard_map(dev_fn, mesh=mesh, in_specs=in_specs,
+                                 out_specs=(P(),) * 5, check_vma=False))
 
 
 def a1_mapconcat_sharded_tuples(et, tlo, thi, cum, w, segs, *,
@@ -601,24 +661,6 @@ def a1_mapc_vmapped(n_levels: int, lcap: int, interpret: bool):
     """vmap of the segmented A1 kernel over a leading session axis (the
     cross-session batcher's fused MapConcatenate launch)."""
     f = functools.partial(a1_mapconcat_kernel, n_levels=n_levels, lcap=lcap,
-                          interpret=interpret)
-    return jax.jit(jax.vmap(f))
-
-
-@functools.lru_cache(maxsize=None)
-def a1_state_vmapped(n_levels: int, lcap: int, interpret: bool):
-    """vmap of the carried A1 kernel over a leading session axis — the
-    cross-session batcher fuses same-shape tenants through this (Pallas
-    lowers the mapped axis onto the grid)."""
-    f = functools.partial(a1_count_state_kernel, n_levels=n_levels,
-                          lcap=lcap, interpret=interpret)
-    return jax.jit(jax.vmap(f))
-
-
-@functools.lru_cache(maxsize=None)
-def a2_state_vmapped(n_levels: int, interpret: bool):
-    """vmap of the carried A2 kernel over a leading session axis."""
-    f = functools.partial(a2_count_state_kernel, n_levels=n_levels,
                           interpret=interpret)
     return jax.jit(jax.vmap(f))
 

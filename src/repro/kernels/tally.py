@@ -1,20 +1,24 @@
-"""Dispatch tally + interpret-mode env accessor (dependency-light).
+"""Dispatch tally, decline exception + interpret-mode env accessor.
 
-This module deliberately imports nothing heavy (no jax, no numpy) so the
-pure-XLA engines in ``core/`` can record kernel→XLA downgrades even when
-the Pallas stack itself is unimportable — the ``ImportError`` arm of the
-graceful-degradation ``except`` clauses is exactly the situation in which
-``kernels.ops`` cannot be loaded.  (``repro.obs.registry`` is pure
-stdlib, so depending on it keeps that property.)
+This module deliberately imports nothing heavy (no jax, no numpy): the
+engines in ``core/`` import it at module load, while ``kernels.ops``
+(which imports ``core``) is only imported lazily at dispatch time.
+(``repro.obs.registry`` is pure stdlib, so depending on it keeps that
+property.)
+
+``KernelDeclined`` is the dispatcher's own decline — no TPU and no
+interpret-mode request, or a segment brick beyond the admission bound.
+It is the only exception the graceful-degradation arms in ``core/``
+catch: a Pallas lowering or Mosaic compile error on the chip is a bug,
+and it propagates instead of rerouting onto the XLA scan.
 
 ``KERNEL_CALLS`` tallies host-side kernel dispatches per kind ("a1",
-"a1_state", "a1_mapc", "a1_mapc_shard", the "a2"/"a2_*" analogues) and —
-since PR 6 — every graceful degradation under a ``fallback:<site>`` kind
+"a1_state", "a1_mapc", "a1_mapc_shard", the "a2"/"a2_*" analogues) and
+every graceful degradation under a ``fallback:<site>`` kind
 (``record_fallback``).  A downgrade that does not move a tally is
 invisible to both the service telemetry and the contract auditor
-(``repro.analysis``), which is how PR 3's silent-bypass bug survived
-review; the auditor's KC105 rule now rejects any
-``except NotImplementedError`` degradation path that does not call
+(``repro.analysis``); the auditor's KC105 rule rejects a degradation arm
+that catches more than ``KernelDeclined`` or does not call
 ``record_fallback``.
 
 Since the obs PR the tally is a *view* over the process-global metrics
@@ -45,6 +49,12 @@ from repro.obs.registry import REGISTRY
 INTERPRET_ENV_VARS = ("REPRO_KERNEL_INTERPRET", "REPRO_INTERPRET_KERNELS")
 
 _FAMILY = "kernel_calls"
+
+
+class KernelDeclined(NotImplementedError):
+    """The kernel dispatcher declined this launch (no TPU and no
+    interpret-mode request, or an unadmitted segment brick); the caller
+    reroutes onto its XLA engine and calls ``record_fallback``."""
 
 
 class _KernelCallsView(MutableMapping):
@@ -94,8 +104,8 @@ def reset_kernel_calls() -> None:
 def record_fallback(site: str) -> None:
     """Record one kernel→XLA graceful degradation at ``site``.
 
-    Every ``except (ImportError, NotImplementedError)`` arm that reroutes
-    a kernel dispatch onto an XLA engine must call this, so downgrades
+    Every ``except KernelDeclined`` arm that reroutes a kernel dispatch
+    onto an XLA engine must call this, so downgrades
     show up in the same tally the kernel dispatches do —
     ``KERNEL_CALLS["fallback:<site>"]``. Enforced by
     ``repro.analysis.contracts`` rule KC105; writing the ``fallback:``

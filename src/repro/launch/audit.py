@@ -36,19 +36,15 @@ def build_report(root: pathlib.Path, trace: bool, sentinel: bool,
     report.extend(active, waived, **summary)
 
     budget = budget_bytes or vmem.VMEM_BUDGET_BYTES
-    try:
-        from repro.kernels.ops import MAX_SEG_BRICK_LW
-    except ImportError:  # audited tree may predate the policy constant
-        MAX_SEG_BRICK_LW = 0
-    if MAX_SEG_BRICK_LW:
-        vf, vs = vmem.check_vmem(MAX_SEG_BRICK_LW, budget=budget)
-        report.extend(vf, **vs)
-        # the dispatch-calibration grid must stay inside the same
-        # admission envelope, or the fitted policy measures fallbacks
-        from repro.core.calibrate import GridSpec
-        cf, cs = vmem.check_calibration_grid(
-            GridSpec().points(), MAX_SEG_BRICK_LW, budget=budget)
-        report.extend(cf, **cs)
+    from repro.kernels.ops import MAX_SEG_BRICK_LW
+    vf, vs = vmem.check_vmem(MAX_SEG_BRICK_LW, budget=budget)
+    report.extend(vf, **vs)
+    # the dispatch-calibration grid must stay inside the same
+    # admission envelope, or the fitted policy measures fallbacks
+    from repro.core.calibrate import GridSpec
+    cf, cs = vmem.check_calibration_grid(
+        GridSpec().points(), MAX_SEG_BRICK_LW, budget=budget)
+    report.extend(cf, **cs)
 
     if trace:
         from repro.analysis import tracecheck

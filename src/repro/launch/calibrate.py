@@ -29,6 +29,7 @@ import sys
 
 from repro.core.calibrate import (CalibrationTable, GridSpec,
                                   calibrate_and_save, device_fingerprint)
+from repro.runtime.compile_cache import enable_compile_cache
 
 from .roofline import HBM_BW, ICI_BW, PEAK_FLOPS
 
@@ -88,6 +89,7 @@ def run(spec: GridSpec, *, out_path: str | None, data_dir: str | None,
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(
         description="Calibrate the dispatch cost model on this host.")
     ap.add_argument("--smoke", action="store_true",

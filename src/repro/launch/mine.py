@@ -32,6 +32,7 @@ import numpy as np
 from repro.core import mine_partitions
 from repro.core.streaming import StreamingMiner
 from repro.data import partition_windows, sym26
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.telemetry import ThroughputMeter
 
 
@@ -50,6 +51,7 @@ def _report(widx, res, max_level):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--seconds", type=int, default=30)
     ap.add_argument("--theta", type=int, default=40,

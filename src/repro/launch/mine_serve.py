@@ -36,6 +36,7 @@ import sys
 from repro.data import partition_windows, sym26
 from repro.obs import REGISTRY, TRACER
 from repro.obs.jaxprof import capture_step
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.service import (BackpressureError, MiningService, SchedulerPolicy,
                            SessionConfig)
 
@@ -84,6 +85,7 @@ def _service_mode(args) -> int:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--sessions", type=int, default=4)
     ap.add_argument("--seconds", type=int, default=10)

@@ -31,10 +31,10 @@ import re
 
 from .registry import REGISTRY
 
-# same message shape the TR205 sentinel parses — one source of truth
-# would couple obs to the analysis plane, so the regex is duplicated and
-# tests/test_obs.py pins the two against each other
-_COMPILE_RE = re.compile(r"Compiling ([\w.<>-]+) with global shapes")
+# jax's compile-log line; JAX 0.9 wraps the name as ``jit(<name>)``, older
+# releases logged it bare. The TR205 sentinel (``analysis.tracecheck``)
+# parses the same lines with this regex.
+_COMPILE_RE = re.compile(r"Compiling (?:jit\()?([\w.<>-]+)\)? with global shapes")
 _LOGGER_NAMES = ("jax._src.interpreters.pxla", "jax._src.dispatch")
 
 _HANDLER: _RecompileHandler | None = None

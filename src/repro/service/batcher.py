@@ -33,14 +33,13 @@ bucket:
   launch concurrently (``batch.self_launch``). Decisions are exported
   as ``batcher_fusion_gate_total{decision=...}``.
 
-The carried Pallas kernels ride the same protocol: ``a1_kernel_scan`` /
-``a2_kernel_scan`` take operands already in kernel brick layout (every
-lane in a group shares (NP, LCAP, MP, EP) shapes — the counters'
-shape-bucketed staging guarantees that), and a fused flush runs one
-``vmap`` of the state-in/state-out ``pallas_call`` per group (Pallas
-lowers the mapped session axis onto the grid, so the whole fleet's
-machines advance in a single kernel launch). Lane results come back in
-kernel layout — the counters keep their state resident there.
+The carried Pallas kernels do not fuse: ``a1_kernel_scan`` /
+``a2_kernel_scan`` take operands already in kernel brick layout and
+launch each request at once on its own thread. Their counters launch one
+fixed shape per level (``ops.STREAM_BLOCK_M`` lanes ×
+``STREAM_BLOCK_E`` events), so a vmapped form would only add a second
+compiled kernel per level (compiled when sessions first step together)
+and copy every lane's state in and out, to save a few launch overheads.
 
 The multi-device MapConcatenate rides it too: ``mapc_sharded_scan``
 fuses same-shape tenants' sharded commits into one launch that vmaps the
@@ -122,8 +121,6 @@ _EV_AXES = {
     "a1": {3: 0, 4: 0},  # ev_types[L], ev_times[L]
     "a2": {3: 0, 4: 0},
     "mapc": {0: 1, 1: 1},  # wt[Q, L], wtt[Q, L]
-    "a1k": {3: 1},  # ev brick [3, EP]
-    "a2k": {3: 1},  # ev brick [2, EP]
     "mapck": {5: 2},  # segment bricks [P, 5, LW]
     "mapcs": {5: 2},  # sharded segment bricks [P, 5, LW]
 }
@@ -158,9 +155,7 @@ def _pad_events(kind: str, args, l_to: int):
         pad[axis] = (0, grow)
         all_types = (kind in ("a1", "a2") and idx == 3) or (kind == "mapc" and idx == 0)
         a = jnp.pad(a, pad, constant_values=PAD_TYPE if all_types else 0)
-        if kind in ("a1k", "a2k"):  # ev brick: types = row 0
-            a = a.at[0, l_to - grow:].set(PAD_TYPE)
-        elif kind in ("mapck", "mapcs"):  # segment brick: types = row 0
+        if kind in ("mapck", "mapcs"):  # segment brick: types = row 0
             a = a.at[:, 0, l_to - grow:].set(PAD_TYPE)
         args[idx] = a
     return tuple(args)
@@ -172,8 +167,6 @@ def _pad_events(kind: str, args, l_to: int):
 _PRIOR_ENGINE = {
     "a1": "ptpe",
     "a2": "ptpe",
-    "a1k": "ptpe",
-    "a2k": "ptpe",
     "mapc": "mapconcatenate",
     "mapck": "mapconcat_kernel",
     "mapcs": "mapconcat_sharded",
@@ -195,10 +188,6 @@ def _policy_prior(key) -> float | None:
         m, n = key[1], key[2]
     elif kind == "mapc":  # ("mapc", mb, n, Q, lcap)
         m, n, q = key[1], key[2], key[3]
-    elif kind == "a1k":  # ("a1k", n, lcap, interp, shape)
-        n, m = key[1], key[4][1]
-    elif kind == "a2k":  # ("a2k", n, interp, shape)
-        n, m = key[1], key[3][1]
     else:  # ("mapck"/"mapcs", n, lcap,
         n, m, q = key[1], key[4][1], key[5]  # interp, shape, P[, d])
         if kind == "mapcs":
@@ -382,10 +371,10 @@ class CrossSessionBatcher:
 
     def a1_kernel_scan(self, args, n_levels: int, lcap: int, interpret: bool):
         # kernel-layout operands: (et[NP,MP], tlo, thi, ev[3,EP],
-        # s[NP,LCAP,MP], po, cnt[8,MP], ovf) — lanes fuse on identical
-        # episode/state shapes; the event brick pads to the group max EP
+        # s[NP,LCAP,MP], po, cnt[8,MP], ovf) — launched at once, never
+        # parked (carried kernels do not fuse; see the module docstring)
         key = ("a1k", n_levels, lcap, interpret, tuple(args[0].shape))
-        return self._submit(
+        return self._run_single_timed(
             _Request("a1k", key, args, None, (n_levels, lcap, interpret), None, None)
         )
 
@@ -393,7 +382,7 @@ class CrossSessionBatcher:
         # kernel-layout operands: (et[NP,MP], tlo, thi, ev[2,EP], s[NP,MP],
         # cnt[8,MP])
         key = ("a2k", n_levels, interpret, tuple(args[0].shape))
-        return self._submit(
+        return self._run_single_timed(
             _Request("a2k", key, args, None, (n_levels, interpret), None, None)
         )
 
@@ -698,29 +687,22 @@ class CrossSessionBatcher:
             REGISTRY.counter("batcher_pad_events_total").inc(waste)
             REGISTRY.counter("batcher_pad_lanes_total").inc(s - len(group))
             lane_args = [_pad_events(kind, r.args, l_to) for r in lanes]
-            if kind not in ("a1k", "a2k", "mapck", "mapcs"):  # M-axis pad
+            if kind not in ("mapck", "mapcs"):  # M-axis pad
                 lane_args = [_pad_m(p, r.spec, r.mb) for p, r in zip(lane_args, lanes)]
             stacked = tuple(
                 jnp.stack([jnp.asarray(p[i]) for p in lane_args])
                 for i in range(len(group[0].args))
             )
         with span("batch.device_launch", kind=kind, lanes=len(group)):
-            if kind in ("a1k", "a2k", "mapck", "mapcs"):
+            if kind in ("mapck", "mapcs"):
                 from repro.kernels import ops as kops
                 if kind == "mapcs":
                     d = group[0].static[3]
                     kops.KERNEL_CALLS["a1_mapc_shard"] += len(group) * d
                     out = kops.a1_mapc_sharded_vmapped(*group[0].static)(*stacked)
                 else:
-                    kops.KERNEL_CALLS[
-                        {"a1k": "a1_state", "a2k": "a2_state",
-                         "mapck": "a1_mapc"}[kind]] += len(group)
-                    if kind == "a1k":
-                        out = kops.a1_state_vmapped(*group[0].static)(*stacked)
-                    elif kind == "a2k":
-                        out = kops.a2_state_vmapped(*group[0].static)(*stacked)
-                    else:
-                        out = kops.a1_mapc_vmapped(*group[0].static)(*stacked)
+                    kops.KERNEL_CALLS["a1_mapc"] += len(group)
+                    out = kops.a1_mapc_vmapped(*group[0].static)(*stacked)
                 results = [tuple(o[i] for o in out) for i in range(len(group))]
             else:
                 if kind == "a1":

@@ -250,6 +250,10 @@ def serve_foreground(config: DaemonConfig) -> None:
 def main(argv=None):
     import argparse
 
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     ap = argparse.ArgumentParser(description="Run the wire-served mining daemon.")
     ap.add_argument(
         "--listen", default="127.0.0.1:0", help='"host:port" or "unix:/path/to.sock"'

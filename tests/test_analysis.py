@@ -50,13 +50,13 @@ CASES = [
      "    try:\n"
      "        from repro.kernels import ops as kops\n"
      "        return kops.a1_count(s)\n"
-     "    except (ImportError, NotImplementedError):\n"
+     "    except KernelDeclined:\n"
      "        return slow(s)\n",
      "def f(s):\n"
      "    try:\n"
      "        from repro.kernels import ops as kops\n"
      "        return kops.a1_count(s)\n"
-     "    except (ImportError, NotImplementedError):\n"
+     "    except KernelDeclined:\n"
      "        record_fallback('site')\n"
      "        return slow(s)\n"),
     ("KC106",
@@ -85,6 +85,20 @@ def test_rule_fires_once_on_seeded_violation(rule, bad, clean):
                          ids=[c[0] for c in CASES])
 def test_rule_quiet_on_clean_twin(rule, bad, clean):
     assert lint_source(clean, "repro/core/fixture.py") == []
+
+
+@pytest.mark.parametrize("caught", ["NotImplementedError", "ImportError",
+                                    "(ImportError, NotImplementedError)"])
+def test_kc105_rejects_arm_broader_than_the_decline(caught):
+    # a Pallas lowering raises NotImplementedError too: catching it (even
+    # with the fallback recorded) would reroute a kernel fault silently
+    src = ("def f(s):\n"
+           "    try:\n"
+           "        return kops.a1_count(s)\n"
+           f"    except {caught}:\n"
+           "        record_fallback('site')\n"
+           "        return slow(s)\n")
+    assert rules_of(lint_source(src, "repro/core/x.py")) == ["KC105"]
 
 
 def test_kernel_def_modules_exempt_from_kc102():
@@ -136,7 +150,15 @@ def test_vmem_policy_fits_budget():
 
 
 def test_vmem_flags_oversized_policy():
-    findings, _ = vmem.check_vmem(1 << 22)
+    # the segmented launches carry the most VMEM (phase-machine scratch);
+    # a budget below their worst case but above every carried launch's
+    # must flag exactly them
+    from repro.kernels.ops import MAX_SEG_BRICK_LW
+    worst_state = max(vmem.a1_state_footprint(vmem.MAX_N, vmem.MAX_LCAP),
+                      vmem.a2_state_footprint(vmem.MAX_N))
+    budget = worst_state + 1
+    assert vmem.mapconcat_footprint(vmem.MAX_N) > budget
+    findings, _ = vmem.check_vmem(MAX_SEG_BRICK_LW, budget=budget)
     assert findings and all(f.rule == "VM302" for f in findings)
 
 
@@ -146,9 +168,10 @@ def test_vmem_flags_unaligned_policy():
 
 
 def test_vmem_footprint_monotone_in_window():
-    small = vmem.mapconcat_footprint(4, 1 << 10)
-    large = vmem.mapconcat_footprint(4, 1 << 17)
-    assert small < large
+    # the segment window streams through SMEM chunks, so the VMEM
+    # footprint grows with the phase-machine scratch (N × LCAP), not LW
+    assert vmem.mapconcat_footprint(4, 4) < vmem.mapconcat_footprint(4, 16)
+    assert vmem.mapconcat_footprint(4) < vmem.mapconcat_footprint(8)
 
 
 def test_vmem_constants_match_kernel_layout():
@@ -158,6 +181,7 @@ def test_vmem_constants_match_kernel_layout():
     assert vmem.LANES == a2_count.LANES
     assert vmem.SUBLANES == a2_count.SUBLANES
     assert vmem.SEG_ROWS == a2_count.SEG_ROWS
+    assert vmem.MAPC_ROWS == a2_count.MAPC_ROWS
     assert vmem.DEFAULT_BLOCK_E == a2_count.DEFAULT_BLOCK_E
 
 
@@ -167,7 +191,7 @@ def test_segment_bricks_enforces_admission_bound():
     wt = np.full((1, 128), -1, np.int32)
     wtt = np.zeros((1, 128), np.int32)
     tau = np.array([0, 100], np.int32)
-    with pytest.raises(NotImplementedError):
+    with pytest.raises(ops.KernelDeclined):
         ops.segment_bricks(wt, wtt, tau, length=ops.MAX_SEG_BRICK_LW * 2)
 
 

@@ -342,8 +342,9 @@ def test_batcher_prior_decodes_seam_keys():
     assert one is not None and one > 0
     mapc = _policy_prior(("mapc", 16, 3, 8, 4))
     assert mapc is not None and mapc > 0
-    # kernel-side seams carry shape tuples; unmeasured engine -> None
-    assert _policy_prior(("a1k", 3, 4, False, (3, 16))) is not None
+    # kernel-side seams carry shape tuples; unmeasured engine -> None;
+    # the carried kernels launch unfused and never reach the gate
+    assert _policy_prior(("a1k", 3, 4, False, (3, 16))) is None
     assert _policy_prior(("mapck", 3, 4, False, (3, 16), 8)) is None
 
 

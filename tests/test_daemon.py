@@ -79,6 +79,10 @@ def spawn_daemon(tmp_path, crash_after=None, extra=()):
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC + (os.pathsep + env["PYTHONPATH"]
                                if env.get("PYTHONPATH") else "")
+    # the daemon's entry point places a persistent compile cache; keep
+    # the test daemons off it (its load warnings would fill the unread
+    # output pipe and stall the daemon)
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
     proc = subprocess.Popen(argv, env=env, stdout=subprocess.PIPE,
                             stderr=subprocess.STDOUT, text=True)
     pidfile = data / "daemon.pid"
@@ -275,3 +279,30 @@ def test_sessions_manifest_written_atomically(tmp_path):
         c.close()
     finally:
         stop_daemon(proc)
+
+
+def test_daemon_parent_touches_no_device_before_detaching(tmp_path):
+    """``mine_serve --listen --daemon`` builds the daemon in the parent and
+    then execs a fresh interpreter that serves. The parent must not bring
+    up a JAX backend first: on a TPU host it would hold the chip the
+    daemon needs."""
+    code = f"""
+import sys
+from jax._src import xla_bridge
+from repro.launch import mine_serve
+from repro.service.daemon import MiningDaemon
+
+def start_detached(self):
+    print("backends", len(xla_bridge._backends))
+    return {{"pid": 0, "address": "-", "data_dir": "-"}}
+
+MiningDaemon.start_detached = start_detached
+sys.argv = ["mine_serve", "--listen", "127.0.0.1:0", "--daemon",
+            "--data-dir", {str(tmp_path)!r}]
+sys.exit(mine_serve.main())
+"""
+    env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert "backends 0" in out.stdout, out.stdout

@@ -59,7 +59,7 @@ def _kernel_available() -> bool:
         from repro.kernels import ops as kops
         kops.kernel_mode()
         return True
-    except (ImportError, NotImplementedError):
+    except kops.KernelDeclined:
         return False
 
 

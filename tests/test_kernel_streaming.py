@@ -217,6 +217,49 @@ def test_streaming_a2_counter_kernel_carried():
     assert ops.KERNEL_CALLS["a2_state"] >= 1
 
 
+def test_streaming_counters_launch_fixed_blocks(monkeypatch):
+    """Batches wider than one lane block and windows longer than one
+    event chunk run as several launches of ONE shape (ops.stream_block),
+    carrying each block's state through the chunks — counts equal the
+    sequential oracles window by window."""
+    rng = np.random.default_rng(7)
+    m = 300  # three 128-lane blocks in interpret mode
+    et = rng.integers(0, NUM_TYPES, size=(m, 3)).astype(np.int32)
+    lo = rng.integers(0, 2, size=(m, 2)).astype(np.int32)
+    eps = EpisodeBatch(et, lo, lo + rng.integers(1, 6, size=(m, 2))
+                       .astype(np.int32))
+    stream = tie_heavy_stream(6, n=700)
+    windows = split_by_index(stream, 2)  # ~350 events: three chunks each
+    shapes = set()
+    a1_call, a2_call = ops.a1_state_call, ops.a2_state_call
+
+    def spy1(*args, **kw):
+        shapes.add(("a1", args[0].shape, args[3].shape))
+        return a1_call(*args, **kw)
+
+    def spy2(*args, **kw):
+        shapes.add(("a2", args[0].shape, args[3].shape))
+        return a2_call(*args, **kw)
+
+    monkeypatch.setattr(ops, "a1_state_call", spy1)
+    monkeypatch.setattr(ops, "a2_state_call", spy2)
+    ctr = StreamingCounter(eps, engine="ptpe", use_kernel=True)
+    a2c = StreamingA2Counter(eps, use_kernel=True)
+    assert len(ctr._keps) == len(a2c._keps) == 3
+    seen = EventStream(stream.types[:0], stream.times[:0], NUM_TYPES)
+    for j, w in enumerate(windows):
+        final = j == len(windows) - 1
+        got1, got2 = ctr.update(w, final=final), a2c.update(w, final=final)
+        seen = EventStream(np.concatenate([seen.types, w.types]),
+                           np.concatenate([seen.times, w.times]), NUM_TYPES)
+        np.testing.assert_array_equal(got2, count_a2_sequential(
+            seen, eps.relaxed()))
+    np.testing.assert_array_equal(got1, count_a1_sequential(stream, eps))
+    bm, be = ops.stream_block(True)
+    assert shapes == {("a1", (8, bm), (3, be)), ("a2", (8, bm), (2, be))}
+    assert ops.KERNEL_CALLS["a1_state"] >= 3 * 2 * 2
+
+
 def test_streaming_state_dict_round_trip_through_kernel_layout():
     """state_dict → load_state_dict → resume: the carried kernel-layout
     state round-trips through the canonical checkpoint form, and the

@@ -78,7 +78,7 @@ def test_kernel_dispatch_declines_on_cpu(monkeypatch):
     rng = np.random.default_rng(3)
     st = random_stream(4, 50, 100, seed=3)
     eps = _batch(rng, 8, 3, 4)
-    with pytest.raises(NotImplementedError):
+    with pytest.raises(ops.KernelDeclined):
         ops.a2_count(st, eps.relaxed())
 
 
@@ -90,3 +90,47 @@ def test_event_padding_boundaries(n_events):
     want = count_a2_sequential(st, eps)
     got = ops.a2_count(st, eps, force="interpret")
     np.testing.assert_array_equal(got, want)
+
+
+def _lowering_refusal(*args, **kwargs):
+    # what Pallas raises when Mosaic cannot lower a primitive
+    raise NotImplementedError(
+        "Unimplemented primitive in Pallas TPU lowering for "
+        "KernelType.TC: scatter.")
+
+
+@pytest.mark.parametrize("engine", ["ptpe", "mapconcat_kernel"])
+def test_kernel_lowering_error_propagates(monkeypatch, engine):
+    """On the compiled (TPU) path a kernel that fails to lower raises out
+    of the dispatcher; it must not reroute onto the XLA scan."""
+    from repro.core import count_a1, count_dispatch
+    from repro.kernels.tally import fallback_counts
+
+    monkeypatch.setattr(ops, "_mode", lambda force=None: False)
+    for name in ("a1_count_kernel", "a1_count_state_kernel",
+                 "a1_mapconcat_kernel"):
+        monkeypatch.setattr(ops, name, _lowering_refusal)
+    rng = np.random.default_rng(11)
+    st = random_stream(4, 300, 600, seed=11)
+    eps = _batch(rng, 8, 3, 4)
+    before = fallback_counts()
+    with pytest.raises(NotImplementedError, match="Pallas TPU lowering"):
+        count_dispatch(st, eps, engine=engine)
+    with pytest.raises(NotImplementedError, match="Pallas TPU lowering"):
+        count_a1(st, eps, use_kernel=True)
+    assert fallback_counts() == before
+
+
+def test_no_tpu_decline_still_reroutes_and_tallies(monkeypatch):
+    from repro.core import count_a1
+    from repro.kernels.tally import fallback_counts
+
+    monkeypatch.delenv("REPRO_INTERPRET_KERNELS", raising=False)
+    monkeypatch.delenv("REPRO_KERNEL_INTERPRET", raising=False)
+    rng = np.random.default_rng(12)
+    st = random_stream(4, 300, 600, seed=12)
+    eps = _batch(rng, 8, 3, 4)
+    before = fallback_counts().get("a1_count", 0)
+    got = count_a1(st, eps, use_kernel=True)
+    np.testing.assert_array_equal(got, count_a1_sequential(st, eps))
+    assert fallback_counts().get("a1_count", 0) == before + 1

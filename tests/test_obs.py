@@ -261,3 +261,10 @@ def test_recompile_regex_accepts_jax_names():
     m = re.match(_COMPILE_RE, "Compiling _a1_scan_core with global shapes "
                               "and types [ShapedArray(int32[128])].")
     assert m and m.group(1) == "_a1_scan_core"
+
+
+def test_recompile_regex_accepts_jit_wrapped_names():
+    # the JAX 0.9 spelling of the same log line
+    m = re.match(_COMPILE_RE, "Compiling jit(a1_count_state_kernel) with "
+                              "global shapes and types [ShapedArray(int32[8,128])].")
+    assert m and m.group(1) == "a1_count_state_kernel"

@@ -230,3 +230,64 @@ def test_throughput_meter_summary():
     s = m.summary()
     assert s["windows"] == 2 and s["events"] == 300
     assert s["events_per_sec"] > 0 and s["steady_events_per_sec"] > 0
+
+
+def test_miner_counters_share_one_attribution_frontier(monkeypatch):
+    """An episode's per-window deltas come from a different candidate
+    batch's counter each window; they telescope to the exact total only if
+    every counter of the miner attributes at the same frontier. Where the
+    segmented kernel is available the per-batch hybrid choice would put
+    small batches on MapConcatenate (W ticks behind), so the miner resolves
+    "hybrid" to PTPE for all of its counters: every reported delta is the
+    A1 count at PTPE's frontier (the window's last timestamp, held back)
+    minus the previous window's, and the deltas sum to the oracle."""
+    from repro.core import hybrid
+    from repro.core.streaming import StreamingMiner
+    from repro.kernels import ops
+
+    monkeypatch.setenv("REPRO_KERNEL_INTERPRET", "1")
+    monkeypatch.setattr(hybrid, "_PROBE_CACHE", True)
+    rng = np.random.default_rng(11)
+    n, k = 900, 8
+    times = (np.cumsum(rng.choice([0, 1, 1, 2, 3], size=n)) + 1).astype(
+        np.int32)
+    types = rng.integers(0, k, size=n).astype(np.int32)
+    stream = EventStream(types, times, k)
+    cuts = np.searchsorted(times, np.arange(1, 6) * (times[-1] // 6))
+    windows = [EventStream(types[a:b], times[a:b], k) for a, b in
+               zip(np.r_[0, cuts], np.r_[cuts, n])]
+    miner = StreamingMiner([(0, 6)], 4, max_level=3)
+    ops.reset_kernel_calls()
+    reported = []
+    for j, w in enumerate(windows):
+        res = miner.update(w, final=j == len(windows) - 1)
+        for batch_, cnt in zip(res.frequent[1:], res.counts[1:]):
+            reported += [(j, tuple(et), int(c))
+                         for et, c in zip(batch_.etypes, cnt)]
+    assert ops.KERNEL_CALLS["a1_state"] > 0
+    assert not ops.KERNEL_CALLS["a1_mapc"]
+    eps_all = sorted({et for _, et, _ in reported})
+    by_n = {}
+    for et in eps_all:
+        by_n.setdefault(len(et), []).append(et)
+    cum = {}
+    for n_, ets in by_n.items():
+        eps = EpisodeBatch(np.int32(ets), np.zeros((len(ets), n_ - 1),
+                                                   np.int32),
+                           np.full((len(ets), n_ - 1), 6, np.int32))
+        for j, w in enumerate(windows):
+            end = (n if j == len(windows) - 1
+                   else int(np.searchsorted(times, w.times[-1])))
+            c = count_a1_sequential(
+                EventStream(types[:end], times[:end], k), eps)
+            cum.update({(j, et): int(x) for et, x in zip(ets, c)})
+    assert len(reported) > 50
+    for j, et, c in reported:
+        assert c == cum[(j, et)] - cum.get((j - 1, et), 0), (j, et)
+    every = {}
+    for j, et, c in reported:
+        every.setdefault(et, []).append(c)
+    whole = [et for et, cs in every.items() if len(cs) == len(windows)]
+    assert whole
+    for et in whole:
+        assert sum(every[et]) == cum[(len(windows) - 1, et)]
