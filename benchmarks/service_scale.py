@@ -48,7 +48,6 @@ except ImportError:  # direct script mode
 
 from repro.data import partition_windows, sym26  # noqa: E402
 from repro.obs import TRACER, span  # noqa: E402
-from repro.obs.trace import step_breakdown  # noqa: E402
 from repro.service import (MiningService, SchedulerPolicy,  # noqa: E402
                            SessionConfig)
 
@@ -76,9 +75,7 @@ def _run_fleet(num_sessions: int, seconds: int, batching: bool):
         batching=batching)
     for sid, cfg, wins, _ in feeds:
         svc.create_session(sid, cfg)
-    # obs spans time the drain loop (bench.fleet is the wall clock) and
-    # step_breakdown() attributes it per phase — barrier wait vs pad/fuse
-    # host work vs device launch — from the same trace the service writes
+    # an obs span times the drain loop (bench.fleet is the wall clock)
     TRACER.clear()
     with span("bench.fleet", sessions=num_sessions, batched=batching):
         for sid, _, wins, _ in feeds:
@@ -87,7 +84,6 @@ def _run_fleet(num_sessions: int, seconds: int, batching: bool):
         svc.pump()
     wall = next(e.dur for e in reversed(TRACER.events())
                 if e.name == "bench.fleet")
-    bd = step_breakdown()
     total_events = sum(n for _, _, _, n in feeds)
     total_windows = sum(len(wins) for _, _, wins, _ in feeds)
     stats = svc.stats()
@@ -103,22 +99,6 @@ def _run_fleet(num_sessions: int, seconds: int, batching: bool):
         "flush_groups": (stats["batcher"]["flush_groups"]
                          if batching else 0),
         "gate": (stats["batcher"]["fusion_gate"] if batching else {}),
-        "breakdown": bd,
-    }
-
-
-def _phase_cols(bd: dict) -> dict:
-    return {
-        "steps": bd["steps"],
-        "snapshot_s": round(bd["snapshot_s"], 4),
-        "bucket_pad_s": round(bd["bucket_pad_s"], 4),
-        "mine_host_s": round(bd["mine_host_s"], 4),
-        "barrier_wait_s": round(bd["barrier_wait_s"], 4),
-        "pad_fuse_s": round(bd["pad_fuse_s"], 4),
-        "device_launch_s": round(bd["device_launch_s"], 4),
-        "stage_s": round(bd["stage_s"], 4),
-        "pipeline_overlap_s": round(bd["pipeline_overlap_s"], 4),
-        "phase_coverage": round(bd["coverage"], 4),
     }
 
 
@@ -142,20 +122,12 @@ def run(sessions=(2, 4, 8), seconds: int = 8, trace_out: str | None = None,
                 fused=r["fused"], batches=r["batches"],
                 flush_groups=r["flush_groups"],
                 gate_fuse=r["gate"].get("fuse", 0),
-                gate_standalone=r["gate"].get("standalone", 0),
-                **_phase_cols(r["breakdown"]))
-        bd = r["breakdown"]
+                gate_standalone=r["gate"].get("standalone", 0))
         print(f"[service-bench] {s:2d} sessions (batched): "
               f"{r['agg_ev_per_s']:,.0f} ev/s aggregate over "
               f"{r['windows']} windows, p99 {r['p99_latency_s']*1e3:.0f} ms,"
               f" {r['fused']} scans fused into {r['batches']} batches"
               f" over {r['flush_groups']} group flushes (gate {r['gate']})")
-        print(f"[service-bench]    phases: wait {bd['barrier_wait_s']:.2f}s"
-              f" pad/fuse {bd['pad_fuse_s']:.2f}s"
-              f" launch {bd['device_launch_s']:.2f}s"
-              f" mine-host {bd['mine_host_s']:.2f}s"
-              f" stage-overlap {bd['pipeline_overlap_s']:.2f}s"
-              f" ({bd['coverage']:.0%} of step wall attributed)")
         if trace_out:
             # trace of the LAST batched fleet size survives (per-run clear)
             n = TRACER.export_chrome(trace_out)
@@ -166,8 +138,7 @@ def run(sessions=(2, 4, 8), seconds: int = 8, trace_out: str | None = None,
             sessions=s, events=r["events"], windows=r["windows"],
             agg_ev_per_s=round(r["agg_ev_per_s"]),
             p99_ms=round(r["p99_latency_s"] * 1e3, 1),
-            flush_groups=0, gate_fuse=0, gate_standalone=0,
-            **_phase_cols(r["breakdown"]))
+            flush_groups=0, gate_fuse=0, gate_standalone=0)
     print(f"[service-bench] {s:2d} sessions (unbatched baseline): "
           f"{r['agg_ev_per_s']:,.0f} ev/s aggregate")
     rep.save()

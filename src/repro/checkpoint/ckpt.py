@@ -24,6 +24,8 @@ from pathlib import Path
 import jax
 import numpy as np
 
+from repro.obs import REGISTRY, span
+
 
 def _path_str(path) -> str:
     parts = []
@@ -45,6 +47,17 @@ def config_fingerprint(obj) -> str:
 
 def save(root: str | os.PathLike, step: int, tree, config_hash: str = "",
          process_index: int | None = None) -> Path:
+    """Write ``tree`` as checkpoint ``step`` under ``root`` (the two-phase
+    protocol above). Returns the published directory."""
+    with span("ckpt.write") as sp:
+        final, leaves, nbytes = _save(root, step, tree, config_hash,
+                                      process_index)
+        sp.note(leaves=leaves, bytes=nbytes)
+    REGISTRY.counter("checkpoint_bytes_total").inc(nbytes)
+    return final
+
+
+def _save(root, step, tree, config_hash, process_index):
     root = Path(root)
     root.mkdir(parents=True, exist_ok=True)
     pidx = jax.process_index() if process_index is None else process_index
@@ -55,6 +68,7 @@ def save(root: str | os.PathLike, step: int, tree, config_hash: str = "",
     tmp.mkdir()
     leaves, treedef = jax.tree_util.tree_flatten_with_path(tree)
     index = []
+    nbytes = 0  # leaf files' bytes
     for path, leaf in leaves:
         key = _path_str(path)
         fname = f"{key.replace('/', '.')}.p{pidx}.npy"
@@ -63,6 +77,7 @@ def save(root: str | os.PathLike, step: int, tree, config_hash: str = "",
             np.save(f, arr)
             f.flush()
             os.fsync(f.fileno())
+            nbytes += f.tell()
         index.append({"key": key, "file": fname, "shape": list(arr.shape),
                       "dtype": str(arr.dtype)})
     manifest = {"step": step, "config_hash": config_hash,
@@ -76,7 +91,7 @@ def save(root: str | os.PathLike, step: int, tree, config_hash: str = "",
     if final.exists():
         shutil.rmtree(final)
     os.rename(tmp, final)  # atomic publish
-    return final
+    return final, len(index), nbytes
 
 
 def latest_step(root: str | os.PathLike) -> int | None:

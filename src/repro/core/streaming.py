@@ -96,6 +96,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.tally import KernelDeclined, record_fallback
+from repro.obs import REGISTRY
 from repro.obs import span as _obs_span
 
 from . import candidates as _cand
@@ -410,10 +411,11 @@ class StreamingCounter:
     def _host_state(self) -> A1State:
         """The carried machines in canonical episode-major layout (unpacks
         the kernel brick when the kernel path is resident)."""
-        if self._kernel:
-            return self._kops.a1_state_unpack(
-                *self._kops.join_lanes(self._kst), self.eps.M, self.eps.N)
-        return self._state
+        with _obs_span("stream.readback", m=self.eps.M):
+            if self._kernel:
+                return self._kops.a1_state_unpack(
+                    *self._kops.join_lanes(self._kst), self.eps.M, self.eps.N)
+            return self._state
 
     def _set_host_state(self, st: A1State) -> None:
         """Install canonical-layout machine state (repacks into the kernel
@@ -662,9 +664,14 @@ class StreamingCounter:
         ``finalize``)."""
         if self.engine == "level1":
             return self._cum.copy()
-        if self.engine == "ptpe":
-            if self._kernel:
-                m = self.eps.M
+        if self.engine != "ptpe" and self._carry is None:
+            return np.zeros(self.eps.M, np.int64)
+        m = self.eps.M
+        with _obs_span("stream.readback", m=m):
+            if self.engine != "ptpe":
+                c = np.asarray(self._carry[1][0], np.int64)
+                flagged = np.asarray(self._carry[3][0]) | self._ovf
+            elif self._kernel:
                 c = np.concatenate([np.asarray(b[2])[0] for b in self._kst]
                                    )[:m].astype(np.int64)
                 flagged = np.concatenate(
@@ -672,11 +679,6 @@ class StreamingCounter:
             else:
                 c = np.asarray(self._state.count, np.int64)
                 flagged = np.asarray(self._state.ovf).copy()
-        else:
-            if self._carry is None:
-                return np.zeros(self.eps.M, np.int64)
-            c = np.asarray(self._carry[1][0], np.int64)
-            flagged = np.asarray(self._carry[3][0]) | self._ovf
         if flagged.any():
             if self.bounded:
                 c = self._restore_exact_bounded(c.copy(), flagged)
@@ -691,21 +693,25 @@ class StreamingCounter:
             raise RuntimeError(
                 "episodes were flagged for exact recount but keep_history "
                 "is off; re-run with keep_history=True")
-        types = np.concatenate([t for t, _ in self._hist] or [_EMPTY_I32])
-        times = np.concatenate([tt for _, tt in self._hist] or [_EMPTY_I32])
-        if self.engine == "ptpe":
-            # dispatched events are always a prefix of the ingested history;
-            # count them explicitly — run() may already have *prepared* (and
-            # history-recorded) the next window while this one's counts are
-            # being read
-            n = self._consumed
-        else:
-            n = int(np.searchsorted(times, self._tau_c, side="right"))
-        stream = EventStream(types[:n], times[:n], self._num_types)
         idx = np.nonzero(flagged)[0]
-        c = c.copy()
-        c[idx] = count_a1(stream, self.eps.select(idx), lcap=self.lcap,
-                          use_kernel=self.use_kernel)
+        REGISTRY.counter("stream_recount_episodes_total").inc(idx.size)
+        with _obs_span("stream.recount", episodes=int(idx.size)) as sp:
+            types = np.concatenate([t for t, _ in self._hist] or [_EMPTY_I32])
+            times = np.concatenate([tt for _, tt in self._hist]
+                                   or [_EMPTY_I32])
+            if self.engine == "ptpe":
+                # dispatched events are always a prefix of the ingested
+                # history; count them explicitly — run() may already have
+                # *prepared* (and history-recorded) the next window while
+                # this one's counts are being read
+                n = self._consumed
+            else:
+                n = int(np.searchsorted(times, self._tau_c, side="right"))
+            sp.note(events=n)
+            stream = EventStream(types[:n], times[:n], self._num_types)
+            c = c.copy()
+            c[idx] = count_a1(stream, self.eps.select(idx), lcap=self.lcap,
+                              use_kernel=self.use_kernel)
         return c
 
     # ------------------------------------------------- bounded memory
@@ -731,19 +737,23 @@ class StreamingCounter:
         """Recount flagged episodes by replaying only the retained suffix
         from their known-exact base state (checkpointed machine state for
         episodes unflagged at the base, oracle escrow otherwise)."""
-        t_all, tt_all = self._suffix_concat()
-        take = self._suffix_take(tt_all)
-        for i in np.nonzero(flagged)[0].tolist():
-            orc = self._escrow.get(i)
-            if orc is not None:
-                orc = orc.copy()  # counts() is a read — never mutate escrow
-            else:
-                orc = _OracleA1(
-                    self.eps.etypes[i], self.eps.tlo[i], self.eps.thi[i],
-                    _lists_from_slots(self._bstate["s"][i],
-                                      self._bstate["ptr"][i]),
-                    int(self._bstate["count"][i]))
-            c[i] = orc.feed(t_all[:take], tt_all[:take])
+        idx = np.nonzero(flagged)[0].tolist()
+        REGISTRY.counter("stream_recount_episodes_total").inc(len(idx))
+        with _obs_span("stream.recount", episodes=len(idx)) as sp:
+            t_all, tt_all = self._suffix_concat()
+            take = self._suffix_take(tt_all)
+            sp.note(events=take)
+            for i in idx:
+                orc = self._escrow.get(i)
+                if orc is not None:
+                    orc = orc.copy()  # counts() is a read — never mutate
+                else:
+                    orc = _OracleA1(
+                        self.eps.etypes[i], self.eps.tlo[i], self.eps.thi[i],
+                        _lists_from_slots(self._bstate["s"][i],
+                                          self._bstate["ptr"][i]),
+                        int(self._bstate["count"][i]))
+                c[i] = orc.feed(t_all[:take], tt_all[:take])
         return c
 
     def _shadow_scan(self, feed_t: np.ndarray, feed_tt: np.ndarray):
@@ -795,27 +805,30 @@ class StreamingCounter:
             s, ptr, cnt, ovf = self._shadow_scan(feed_t, feed_tt)
         pend = sorted(set(np.nonzero(ovf)[0].tolist()) | set(self._escrow))
         if pend:
-            t_f = int(feed_tt[-1]) if take else None
-            escrow: dict[int, _OracleA1] = {}
-            for i in pend:
-                orc = self._escrow.get(i)
-                if orc is None:
-                    orc = _OracleA1(
-                        self.eps.etypes[i], self.eps.tlo[i], self.eps.thi[i],
-                        _lists_from_slots(self._bstate["s"][i],
-                                          self._bstate["ptr"][i]),
-                        int(self._bstate["count"][i]))
-                orc.feed(feed_t, feed_tt)
-                cnt[i] = orc.count
-                lists = orc.pruned(t_f) if t_f is not None else orc.lists
-                fit = _slots_from_lists(lists, self.lcap)
-                if fit is None:
-                    escrow[i] = orc
-                    ovf[i] = True
-                else:
-                    s[i], ptr[i] = fit
-                    ovf[i] = False
-            self._escrow = escrow
+            REGISTRY.counter("stream_recount_episodes_total").inc(len(pend))
+            with _obs_span("stream.recount", episodes=len(pend), events=take):
+                t_f = int(feed_tt[-1]) if take else None
+                escrow: dict[int, _OracleA1] = {}
+                for i in pend:
+                    orc = self._escrow.get(i)
+                    if orc is None:
+                        orc = _OracleA1(
+                            self.eps.etypes[i], self.eps.tlo[i],
+                            self.eps.thi[i],
+                            _lists_from_slots(self._bstate["s"][i],
+                                              self._bstate["ptr"][i]),
+                            int(self._bstate["count"][i]))
+                    orc.feed(feed_t, feed_tt)
+                    cnt[i] = orc.count
+                    lists = orc.pruned(t_f) if t_f is not None else orc.lists
+                    fit = _slots_from_lists(lists, self.lcap)
+                    if fit is None:
+                        escrow[i] = orc
+                        ovf[i] = True
+                    else:
+                        s[i], ptr[i] = fit
+                        ovf[i] = False
+                self._escrow = escrow
         self._bstate = {"s": s, "ptr": ptr, "count": cnt, "ovf": ovf}
         self._base_consumed += take
         self._suffix = ([(t_all[take:], tt_all[take:])]
@@ -1065,10 +1078,11 @@ class StreamingA2Counter:
         self._state = None
 
     def _host_state(self) -> A2State:
-        if self._kernel:
-            return self._kops.a2_state_unpack(
-                *self._kops.join_lanes(self._kst), self.eps.M, self.eps.N)
-        return self._state
+        with _obs_span("stream.readback", m=self.eps.M):
+            if self._kernel:
+                return self._kops.a2_state_unpack(
+                    *self._kops.join_lanes(self._kst), self.eps.M, self.eps.N)
+            return self._state
 
     def _set_host_state(self, st: A2State) -> None:
         if self._kernel:
@@ -1078,8 +1092,9 @@ class StreamingA2Counter:
             self._state = st
 
     def _kernel_counts(self) -> np.ndarray:
-        return np.concatenate([np.asarray(b[1])[0] for b in self._kst]
-                              )[: self.eps.M].astype(np.int64)
+        with _obs_span("stream.readback", m=self.eps.M):
+            return np.concatenate([np.asarray(b[1])[0] for b in self._kst]
+                                  )[: self.eps.M].astype(np.int64)
 
     def update(self, window: EventStream, final: bool = False) -> np.ndarray:
         real = window.types != PAD_TYPE
@@ -1089,12 +1104,17 @@ class StreamingA2Counter:
                 self._cum += count_level1(window, self.eps.etypes[:, 0])
             out = self._cum.copy()
         elif n == 0:
-            out = (self._kernel_counts() if self._kernel
-                   else np.asarray(self._state.count, np.int64))
+            if self._kernel:
+                out = self._kernel_counts()
+            else:
+                with _obs_span("stream.readback", m=self.eps.M):
+                    out = np.asarray(self._state.count, np.int64)
         elif self._kernel:
-            chunks = self._kops.event_chunks(window.types[real],
-                                             window.times[real],
-                                             with_dup=False, width=self._be)
+            with _obs_span("stream.prepare", final=final):
+                chunks = self._kops.event_chunks(window.types[real],
+                                                 window.times[real],
+                                                 with_dup=False,
+                                                 width=self._be)
             for b, ep_rows in enumerate(self._keps):
                 for ev in chunks:
                     args = ep_rows + (ev,) + self._kst[b]
@@ -1119,7 +1139,8 @@ class StreamingA2Counter:
                      jnp.asarray(padded.types), jnp.asarray(padded.times),
                      st.s, st.count))
                 self._state = A2State(s=s, count=c)
-                out = np.asarray(c, np.int64)
+                with _obs_span("stream.readback", m=self.eps.M):
+                    out = np.asarray(c, np.int64)
             else:
                 with _obs_span("stream.launch", kind="a2_scan"):
                     out, self._state = count_single_slot(
@@ -1264,10 +1285,12 @@ class StreamingMiner:
         # group frontier) with MapConcatenate (W ticks behind), so the
         # miner resolves "hybrid" to PTPE for all of its counters.
         engine = "ptpe" if self.engine == "hybrid" else self.engine
-        return StreamingCounter(
-            eps, engine=engine, lcap=self.lcap,
-            num_segments=self.num_segments, use_kernel=self.use_kernel,
-            executor=self.executor, checkpoint_interval=self.history_limit)
+        with _obs_span("stream.counter_init", kind="a1", m=eps.M):
+            return StreamingCounter(
+                eps, engine=engine, lcap=self.lcap,
+                num_segments=self.num_segments, use_kernel=self.use_kernel,
+                executor=self.executor,
+                checkpoint_interval=self.history_limit)
 
     def _update_fragments(self, frags, window: EventStream, final: bool):
         """Advance every fragment of a tracked set; returns the
@@ -1292,9 +1315,12 @@ class StreamingMiner:
         window."""
         if counter.windows_seen < self._hist_base:
             counter.fast_forward(self._hist_base)
-        while counter.windows_seen < self._p:
-            counter.update(self._history[counter.windows_seen
-                                         - self._hist_base])
+        if counter.windows_seen < self._p:
+            with _obs_span("stream.replay",
+                           windows=self._p - counter.windows_seen):
+                while counter.windows_seen < self._p:
+                    counter.update(self._history[counter.windows_seen
+                                                 - self._hist_base])
         return counter.update(window, final=final)
 
     def _count_level(self, cand: EpisodeBatch, window: EventStream,
@@ -1316,10 +1342,11 @@ class StreamingMiner:
         if self.two_pass:
             a2c = self._a2.get(key)
             if a2c is None:
-                a2c = self._a2[key] = StreamingA2Counter(
-                    cand, executor=self.executor,
-                    bounded=self.history_limit is not None,
-                    use_kernel=self.use_kernel)
+                with _obs_span("stream.counter_init", kind="a2", m=m):
+                    a2c = self._a2[key] = StreamingA2Counter(
+                        cand, executor=self.executor,
+                        bounded=self.history_limit is not None,
+                        use_kernel=self.use_kernel)
             a2_cum = self._sync(a2c, window, final)
             a2_prev = (a2c.snapshots[-2] if len(a2c.snapshots) >= 2
                        else zeros)
@@ -1425,10 +1452,13 @@ class StreamingMiner:
         while level <= self.max_level and seed_batch is not None \
                 and seed_batch.M > 0:
             t0 = time.perf_counter()
-            if level == 2:
-                cand = _cand.level2(seed_batch.etypes[:, 0], self.intervals)
-            else:
-                cand = _cand.join_next_level(seed_batch)
+            with _obs_span("mine.candidates", level=level) as sp:
+                if level == 2:
+                    cand = _cand.level2(seed_batch.etypes[:, 0],
+                                        self.intervals)
+                else:
+                    cand = _cand.join_next_level(seed_batch)
+                sp.note(m=0 if cand is None else cand.M)
             if cand is None or cand.M == 0:
                 break
             cvec, freq, surv, seed = self._count_level(cand, w, final)

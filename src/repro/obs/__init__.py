@@ -15,13 +15,15 @@ Three coordinated layers, all cheap enough to be on by default:
   thin facades over the same numbers.
 
 * ``trace`` — nested host-side spans threaded through the full window
-  lifecycle (``ingest -> schedule -> bucket/pad -> fused launch -> kernel
-  dispatch -> commit/stitch -> checkpoint``). Spans land in a fixed-size
-  ring buffer (O(1) per span, two clock reads, no device sync) and export
-  as JSONL or Chrome trace-event JSON — load the latter straight into
-  Perfetto / ``chrome://tracing``. ``step_breakdown()`` turns one
-  scheduler step's spans into the per-phase attribution (barrier wait vs
-  pad/fuse host work vs device launch) the batching regression needs.
+  lifecycle (``wire.ingest -> schedule.step -> session.mine_window ->
+  mine.candidates / stream.counter_init / stream.replay / stream.launch /
+  stream.readback / stream.recount -> stream.checkpoint ->
+  service.checkpoint (ckpt.state, ckpt.write) -> wire.deliver``; the
+  full tree is in ``trace``'s docstring). Spans land in a fixed-size
+  ring buffer (O(1) per span, two clock reads, no device sync; evictions
+  counted in ``TRACER.dropped``) and export as JSONL or Chrome
+  trace-event JSON — load the latter straight into Perfetto /
+  ``chrome://tracing``.
 
 * ``jaxprof`` — device-side hooks: ``jax.profiler`` trace annotations
   around the instrumented kernel entry points, an always-on recompilation

@@ -523,8 +523,7 @@ class CrossSessionBatcher:
         self._run_flushes(ready)
         # the parked time: co-tenant staging skew plus whichever thread
         # executes this group's flush (it completed the group, so it runs
-        # the launch while we park). obs.trace.step_breakdown separates
-        # wait from flush work.
+        # the launch while we park).
         with span("batch.barrier_wait", kind=req.kind):
             while not req.event.wait(timeout=self.flush_deadline_s):
                 late = []
@@ -588,7 +587,7 @@ class CrossSessionBatcher:
                 self._dispatch_group(sub)
 
     def _dispatch_group(self, sub: list[_Request]) -> None:
-        kind, key, lanes = sub[0].kind, sub[0].key, len(sub)
+        key, lanes = sub[0].key, len(sub)
         if lanes == 1:
             decision = "singleton"
         elif not self.fusion_gate:
@@ -598,8 +597,6 @@ class CrossSessionBatcher:
         with self._lock:
             self.gate_decisions[decision] += 1
         REGISTRY.counter("batcher_fusion_gate_total", decision=decision).inc()
-        with span("batch.gate", kind=kind, lanes=lanes, decision=decision):
-            pass  # zero-width marker: step_breakdown tallies decisions
         if decision != "fuse":
             # singleton fall-through or measured loss: release every
             # lane to run its own plain dispatch
@@ -720,9 +717,8 @@ class CrossSessionBatcher:
 
     def _run_single_timed(self, req: _Request):
         """One lane's plain dispatch, in the owning thread, timed for the
-        cost model. ``batch.self_launch`` is a per-thread device phase in
-        ``step_breakdown`` — concurrent self-launches must not read as
-        serialized flush work."""
+        cost model. ``batch.self_launch`` is a span of its own thread, so
+        concurrent self-launches do not read as serialized flush work."""
         t0 = time.perf_counter()
         with span("batch.self_launch", kind=req.kind):
             out = self._run_single(req)

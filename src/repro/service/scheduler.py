@@ -169,7 +169,9 @@ class RoundRobinScheduler:
 
     # ------------------------------------------------------- ingestion
 
-    def submit(self, session_id: str, window: EventStream, final: bool = False) -> None:
+    def submit(self, session_id: str, window: EventStream, final: bool = False) -> int:
+        """Queue one window for ``session_id``; returns its session-local
+        index (``MiningSession.enqueue``)."""
         s = self.session(session_id)
         if s.queue_depth >= self.policy.max_pending_windows:
             # the producer must shed or spool this window upstream —
@@ -180,8 +182,9 @@ class RoundRobinScheduler:
             raise BackpressureError(
                 f"session {session_id!r} queue at depth {s.queue_depth} "
                 f"(cap {self.policy.max_pending_windows})")
-        s.enqueue(window, final=final)
+        idx = s.enqueue(window, final=final)
         REGISTRY.gauge("scheduler_queue_depth").set(self.pending_windows)
+        return idx
 
     @property
     def pending_windows(self) -> int:

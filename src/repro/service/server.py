@@ -82,11 +82,12 @@ class MiningService:
 
     # ------------------------------------------------------ ingest/poll
 
-    def ingest(self, session_id: str, window: EventStream, final: bool = False) -> None:
+    def ingest(self, session_id: str, window: EventStream, final: bool = False) -> int:
         """Queue one partition window (raises ``BackpressureError`` when
-        the tenant's queue is full — shed or spool upstream)."""
+        the tenant's queue is full — shed or spool upstream). Returns the
+        window's session-local index, the ``window_idx`` of its delta."""
         with span("service.ingest", session=session_id):
-            self.scheduler.submit(session_id, window, final=final)
+            return self.scheduler.submit(session_id, window, final=final)
 
     def pump(self, max_steps: int | None = None) -> int:
         """Run batched scheduler steps until queues drain (or the step
@@ -191,8 +192,14 @@ class MiningService:
                 REGISTRY.counter("recovery_windows_requeued_total").value
             ),
             "checkpoints": int(REGISTRY.counter("service_checkpoints_total").value),
+            "checkpoint_bytes": int(REGISTRY.counter("checkpoint_bytes_total").value),
             "quiesced_preps": int(
                 REGISTRY.counter("scheduler_quiesced_preps_total").value
+            ),
+        }
+        out["streaming"] = {
+            "recount_episodes": int(
+                REGISTRY.counter("stream_recount_episodes_total").value
             ),
         }
         out["daemon"] = {

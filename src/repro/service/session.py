@@ -144,11 +144,15 @@ class MiningSession:
 
     # ------------------------------------------------------------- data
 
-    def enqueue(self, window: EventStream, final: bool = False) -> None:
+    def enqueue(self, window: EventStream, final: bool = False) -> int:
+        """Queue one window; returns its session-local index, the
+        ``window_idx`` its delta will carry."""
         if self.closed:
             raise RuntimeError(f"session {self.session_id} is closed")
+        idx = self.windows_done + self.queue_depth
         self.pending.append((window, final))
         self.closed = final
+        return idx
 
     @property
     def queue_depth(self) -> int:
@@ -224,6 +228,12 @@ class MiningSession:
         queue — a restored session replays nothing and drops nothing (the
         miner is already past queued deltas' windows, so they could never
         be regenerated)."""
+        with span("ckpt.state") as sp:
+            d = self._state_dict()
+            sp.note(leaves=len(d))
+        return d
+
+    def _state_dict(self) -> dict[str, np.ndarray]:
         d = {f"miner/{k}": v for k, v in self.miner.state_dict().items()}
         d["windows_done"] = np.asarray(self.windows_done, np.int64)
         d["closed"] = np.asarray(int(self.closed), np.int64)

@@ -708,7 +708,7 @@ class WireServer:
     def _handle_batch(self, frame: Frame) -> list[Frame]:
         sid, stream, final = decode_events(frame.payload)
         seq = frame.seq
-        with self._lock, span("wire.ingest", session=sid, seq=seq):
+        with self._lock, span("wire.ingest", session=sid, seq=seq) as sp:
             st = self.sessions.get(sid)
             if st is None:
                 return [
@@ -732,7 +732,7 @@ class WireServer:
                     )
                 ]
             try:
-                self.service.ingest(sid, stream, final=final)
+                sp.note(window=self.service.ingest(sid, stream, final=final))
             except BackpressureError as e:
                 REGISTRY.counter("wire_backpressure_total").inc()
                 depth = self.service.session(sid).queue_depth
@@ -768,6 +768,10 @@ class WireServer:
                 fresh = self.service.poll(sid)
             except UnknownSessionError:
                 fresh = []
+            if fresh:
+                with span("wire.deliver", session=sid,
+                          windows=[d.window_idx for d in fresh]):
+                    pass  # zero width: these deltas' first hand-out
             st.delta_cache.extend(delta_payload(d) for d in fresh)
             return [Frame(FrameType.DELTAS, frame.seq, _j({
                 "session": sid, "deltas": st.delta_cache,

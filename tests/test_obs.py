@@ -1,8 +1,9 @@
 """Observability-plane tests: span exception-safety and nesting (down
-through a batched service run), registry snapshot/delta determinism, the
-KERNEL_CALLS facade ≡ registry equivalence (including a forced
-kernel→XLA degradation), Chrome trace-event export schema, and the
-jaxprof/tracecheck recompile-regex pin."""
+through a batched service run, and every span inside a window's life
+with its arguments), the ring's eviction count, registry snapshot/delta
+determinism, the KERNEL_CALLS facade ≡ registry equivalence (including a
+forced kernel→XLA degradation), Chrome trace-event export schema, and
+the jaxprof/tracecheck recompile-regex pin."""
 
 import json
 import re
@@ -21,7 +22,7 @@ from repro.kernels.tally import (KERNEL_CALLS, fallback_counts,
 from repro.obs import REGISTRY, TRACER
 from repro.obs.jaxprof import _COMPILE_RE, ensure_recompile_listener
 from repro.obs.registry import Registry
-from repro.obs.trace import Tracer, step_breakdown
+from repro.obs.trace import Tracer
 from repro.service import MiningService, SchedulerPolicy, SessionConfig
 
 
@@ -59,6 +60,33 @@ def test_span_disabled_records_nothing():
     with tr.span("x"):
         pass
     assert tr.events() == []
+
+
+def test_note_adds_args_only_when_on():
+    tr = Tracer()
+    with tr.span("x", a=1) as sp:
+        sp.note(b=2)
+    with tr.span("y") as sp:
+        sp.note(c=3)
+    assert [e.args for e in tr.events()] == [{"a": 1, "b": 2}, {"c": 3}]
+    tr.enabled = False
+    with tr.span("z") as sp:
+        sp.note(d=4)
+    assert len(tr.events()) == 2
+
+
+def test_ring_counts_evicted_spans():
+    tr = Tracer(capacity=4)
+    for i in range(10):
+        with tr.span("s", i=i):
+            pass
+    assert tr.dropped == 6
+    assert [e.args["i"] for e in tr.events()] == [6, 7, 8, 9]
+    tr.clear()
+    assert tr.dropped == 0 and tr.events() == []
+    with tr.span("s"):
+        pass
+    assert tr.dropped == 0
 
 
 def test_spans_are_per_thread():
@@ -220,9 +248,14 @@ def test_spans_nest_through_batched_service():
     for m in (e for e in evs if e.name == "session.mine_window"):
         assert any(s.t0 <= m.t0 and m.t0 + m.dur <= s.t0 + s.dur + 1e-6
                    for s in steps)
-    bd = step_breakdown()
-    assert bd["steps"] == len(steps) > 0
-    assert 0.5 < bd["coverage"] <= 1.05
+    # candidate generation and the batcher's parking nest inside a
+    # window's mining on the lane's own thread
+    mines = [e for e in evs if e.name == "session.mine_window"]
+    for name in ("mine.candidates", "batch.barrier_wait"):
+        inner = [e for e in evs if e.name == name]
+        assert inner, name
+        assert all(_inside(e, mines) for e in inner), name
+    assert len(steps) > 0
 
     stats = svc.stats()
     assert stats["scheduler"]["queue_depth"] == 0
@@ -232,6 +265,71 @@ def test_spans_nest_through_batched_service():
     assert stats["metrics"]["scheduler_steps_total"] >= len(steps)
     for sid in feeds:
         assert f"session_windows_total{{session={sid}}}" in stats["metrics"]
+
+
+def _inside(e, parents) -> bool:
+    """``e`` lies within one of ``parents`` on the same thread, deeper."""
+    return any(p.tid == e.tid and p.depth < e.depth and p.t0 <= e.t0
+               and e.t0 + e.dur <= p.t0 + p.dur + 1e-6 for p in parents)
+
+
+# where each span of a window's life may sit, and the arguments it carries
+_WINDOW_SPANS = {
+    "mine.candidates": (("session.mine_window",), {"level", "m"}),
+    "stream.counter_init": (("session.mine_window",), {"kind", "m"}),
+    "stream.replay": (("session.mine_window",), {"windows"}),
+    "stream.readback": (("session.mine_window", "ckpt.state"), {"m"}),
+    "stream.recount": (("session.mine_window",), {"episodes", "events"}),
+    "ckpt.state": (("service.checkpoint", "schedule.snapshot",
+                    "schedule.stage"), {"leaves"}),
+    "ckpt.write": (("service.checkpoint",), {"leaves", "bytes"}),
+}
+
+
+def test_window_life_spans_nest_and_carry_args(tmp_path):
+    """A service with a checkpoint directory mines a few Sym26 windows:
+    every span inside a window's life appears, inside the span that
+    causes it, with its arguments, and the operator counters agree with
+    the spans' arguments."""
+    TRACER.clear()
+    recounted = REGISTRY.counter("stream_recount_episodes_total").value
+    written = REGISTRY.counter("checkpoint_bytes_total").value
+    svc = MiningService(policy=SchedulerPolicy(max_sessions=2))
+    # lcap 1 overflows the bounded lists, so episodes are recounted;
+    # history_limit 2 advances the counters' base every other window
+    cfg = SessionConfig(intervals=((5, 10),), theta=3, max_level=3,
+                        window_ms=500, history_limit=2, lcap=1)
+    stream, _ = sym26(seconds=3, rate_hz=12.0, seed=7)
+    sid = svc.create_session("life", cfg)
+    for j, w in enumerate(partition_windows(stream, 500)):
+        assert svc.ingest(sid, w) == j  # the window's session-local index
+        svc.pump()
+        svc.checkpoint_all(tmp_path)
+    evs = TRACER.events()
+    assert TRACER.dropped == 0
+    mines = [e for e in evs if e.name == "session.mine_window"]
+    assert [e.args["window"] for e in mines] == list(range(len(mines)))
+    assert [d.window_idx for d in svc.poll(sid)] == list(range(len(mines)))
+    for name, (parents, args) in _WINDOW_SPANS.items():
+        got = [e for e in evs if e.name == name]
+        assert got, name
+        outer = [e for e in evs if e.name in parents]
+        assert all(_inside(e, outer) for e in got), name
+        assert all(args <= set(e.args) for e in got), name
+    # the base advance reads the counters back and recounts inside itself
+    adv = [e for e in evs if e.name == "stream.checkpoint"]
+    assert any(_inside(e, adv) for e in evs if e.name == "stream.recount")
+    assert any(_inside(e, adv) for e in evs if e.name == "stream.readback")
+    assert any(_inside(e, [c for c in evs if c.name == "service.checkpoint"])
+               for e in evs if e.name == "ckpt.state")
+    assert {e.args["kind"] for e in evs
+            if e.name == "stream.counter_init"} == {"a1", "a2"}
+    assert all(e.args["windows"] >= 1 for e in evs if e.name == "stream.replay")
+    stats = svc.stats()
+    assert (stats["streaming"]["recount_episodes"] - recounted
+            == sum(e.args["episodes"] for e in evs if e.name == "stream.recount") > 0)
+    assert (stats["recovery"]["checkpoint_bytes"] - written
+            == sum(e.args["bytes"] for e in evs if e.name == "ckpt.write") > 0)
 
 
 # ---------------------------------------------------------------- jaxprof

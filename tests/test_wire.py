@@ -26,7 +26,7 @@ import numpy as np
 import pytest
 
 from repro.core import EventStream
-from repro.obs import REGISTRY
+from repro.obs import REGISTRY, TRACER
 from repro.service import (MiningService, MiningSession, SchedulerPolicy,
                            SessionConfig)
 from repro.service.client import MiningClient
@@ -171,6 +171,38 @@ def test_wire_serving_bit_identical_to_standalone(server):
     ref = local_reference(cfg, wins)
     assert [r["episodes"] for r in ref] == [g["episodes"] for g in got]
     c.close()
+
+
+def test_one_window_carries_one_id_across_layers(server):
+    """Over loopback, a window's ``wire.ingest``, ``session.mine_window``
+    and the ``wire.deliver`` that first hands out its delta name the same
+    (session, window); the delta's ``window_idx`` is that window."""
+    TRACER.clear()
+    cfg = small_config()
+    wins = split_by_index(tie_heavy_stream(5, n=120), 3)
+    c = MiningClient(server.address, "ids", cfg, rng_seed=0)
+    for j, w in enumerate(wins):
+        c.submit(w, final=(j == len(wins) - 1))
+    got = sorted(d["window_idx"] for d in c.drain(deadline_s=120))
+    c.close()
+    evs = [e for e in TRACER.events() if (e.args or {}).get("session") == "ids"]
+    ingested = [(e.args["session"], e.args["window"]) for e in evs
+                if e.name == "wire.ingest" and "window" in e.args]
+    mined = [(e.args["session"], e.args["window"]) for e in evs
+             if e.name == "session.mine_window"]
+    delivered = [("ids", w) for e in evs if e.name == "wire.deliver"
+                 for w in e.args["windows"]]
+    want = [("ids", j) for j in range(len(wins))]
+    assert got == list(range(len(wins)))
+    assert ingested == mined == sorted(delivered) == want
+    # each window's delivery follows its mining, which follows its ingest
+    end = {(e.name, e.args.get("window")): e.t0 + e.dur for e in evs}
+    start = {(e.name, e.args.get("window")): e.t0 for e in evs}
+    for e in evs:
+        if e.name == "wire.deliver":
+            for w in e.args["windows"]:
+                assert end[("wire.ingest", w)] <= start[("session.mine_window", w)]
+                assert end[("session.mine_window", w)] <= e.t0
 
 
 def poll_until(sock, sid, want, deadline_s=120.0, req_base=8_100_000):
