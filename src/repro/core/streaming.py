@@ -176,13 +176,20 @@ class _OracleA1:
         return _OracleA1(self.et, self.tlo, self.thi, self.lists, self.count)
 
     def feed(self, types: np.ndarray, times: np.ndarray) -> int:
-        """Scan a chunk of events; returns the cumulative exact count."""
+        """Scan a chunk of events; returns how many of them were stepped.
+
+        Only events of the episode's own types are stepped: any other event
+        matches no level and leaves the machine as it was. The newest-first
+        walk stops at the first entry too old to match (``t - v > thi``):
+        that entry and every older one are dead for all later events too,
+        so they are dropped, which keeps a carried machine's lists at their
+        live size."""
+        types = np.asarray(types)
+        keep = np.isin(types, self.et)
+        ev = types[keep].tolist()
         n, et, tlo, thi = self.n, self.et, self.tlo, self.thi
         s, count = self.lists, self.count
-        for e, t in zip(np.asarray(types).tolist(),
-                        np.asarray(times).tolist()):
-            if e < 0:  # PAD_TYPE
-                continue
+        for e, t in zip(ev, np.asarray(times)[keep].tolist()):
             completed = False
             for i in range(n - 1, -1, -1):  # top-down over levels
                 if e != et[i]:
@@ -190,8 +197,13 @@ class _OracleA1:
                 if i == 0:
                     s[0].append(t)
                     continue
-                for t_prev in reversed(s[i - 1]):
-                    if tlo[i - 1] < t - t_prev <= thi[i - 1]:
+                prev = s[i - 1]
+                for k in range(len(prev) - 1, -1, -1):
+                    d = t - prev[k]
+                    if d > thi[i - 1]:
+                        del prev[:k + 1]
+                        break
+                    if d > tlo[i - 1]:
                         if i == n - 1:
                             count += 1
                             s = [[] for _ in range(n)]
@@ -202,7 +214,7 @@ class _OracleA1:
                 if completed:
                     break
         self.lists, self.count = s, count
-        return count
+        return len(ev)
 
     def pruned(self, t_frontier: int) -> list[list[int]]:
         """Live entries only: a level-``i`` entry ``v`` is dead once
@@ -349,6 +361,9 @@ class StreamingCounter:
             # escrow for episodes whose exact lists overflow lcap
             self._suffix: list[tuple[np.ndarray, np.ndarray]] = []
             self._escrow: dict[int, _OracleA1] = {}
+            # derived, never checkpointed: each recounted episode's oracle
+            # and how many suffix events it has been fed since the base
+            self._memo: dict[int, tuple[_OracleA1, int]] = {}
             self._base_consumed = 0
             self._wsb = 0  # fed windows since the last base advance
             self._bstate = {
@@ -733,27 +748,53 @@ class StreamingCounter:
             return 0
         return int(np.searchsorted(tt_all, self._tau_c, side="right"))
 
+    def _base_oracle(self, i: int) -> _OracleA1:
+        """Episode ``i``'s exact machine at the suffix base: a copy of its
+        escrow oracle, or its checkpointed bounded lists (exact for an
+        episode unflagged at the base)."""
+        orc = self._escrow.get(i)
+        if orc is not None:
+            return orc.copy()
+        return _OracleA1(
+            self.eps.etypes[i], self.eps.tlo[i], self.eps.thi[i],
+            _lists_from_slots(self._bstate["s"][i], self._bstate["ptr"][i]),
+            int(self._bstate["count"][i]))
+
+    def _recount(self, idx, t_all: np.ndarray, tt_all: np.ndarray,
+                 take: int) -> tuple[list[_OracleA1], int]:
+        """Bring each episode of ``idx`` exactly to suffix event ``take``.
+
+        An episode recounted earlier in this interval resumes from its
+        carried oracle and is fed only the events committed since; the
+        rest start from their base state. Returns the oracles (now carried
+        at ``take``) and how many events were stepped."""
+        carried = fed = 0
+        out = []
+        for i in idx:
+            orc, done = self._memo.get(i, (None, 0))
+            if orc is None:
+                orc = self._base_oracle(i)
+            else:
+                carried += 1
+            fed += orc.feed(t_all[done:take], tt_all[done:take])
+            self._memo[i] = (orc, take)
+            out.append(orc)
+        REGISTRY.counter("stream_recount_episodes_total").inc(len(out))
+        REGISTRY.counter("stream_recount_carried_total").inc(carried)
+        REGISTRY.counter("stream_recount_events_total").inc(fed)
+        return out, fed
+
     def _restore_exact_bounded(self, c: np.ndarray, flagged: np.ndarray):
-        """Recount flagged episodes by replaying only the retained suffix
-        from their known-exact base state (checkpointed machine state for
-        episodes unflagged at the base, oracle escrow otherwise)."""
+        """Recount flagged episodes by replaying the retained suffix from
+        their known-exact base state, carried forward between reads (the
+        checkpointed state and the escrow are never mutated here)."""
         idx = np.nonzero(flagged)[0].tolist()
-        REGISTRY.counter("stream_recount_episodes_total").inc(len(idx))
         with _obs_span("stream.recount", episodes=len(idx)) as sp:
             t_all, tt_all = self._suffix_concat()
             take = self._suffix_take(tt_all)
-            sp.note(events=take)
-            for i in idx:
-                orc = self._escrow.get(i)
-                if orc is not None:
-                    orc = orc.copy()  # counts() is a read — never mutate
-                else:
-                    orc = _OracleA1(
-                        self.eps.etypes[i], self.eps.tlo[i], self.eps.thi[i],
-                        _lists_from_slots(self._bstate["s"][i],
-                                          self._bstate["ptr"][i]),
-                        int(self._bstate["count"][i]))
-                c[i] = orc.feed(t_all[:take], tt_all[:take])
+            orcs, fed = self._recount(idx, t_all, tt_all, take)
+            c[idx] = [orc.count for orc in orcs]
+            sp.note(events=take, fed=fed)
         return c
 
     def _shadow_scan(self, feed_t: np.ndarray, feed_tt: np.ndarray):
@@ -805,20 +846,13 @@ class StreamingCounter:
             s, ptr, cnt, ovf = self._shadow_scan(feed_t, feed_tt)
         pend = sorted(set(np.nonzero(ovf)[0].tolist()) | set(self._escrow))
         if pend:
-            REGISTRY.counter("stream_recount_episodes_total").inc(len(pend))
-            with _obs_span("stream.recount", episodes=len(pend), events=take):
+            with _obs_span("stream.recount", episodes=len(pend),
+                           events=take) as sp:
                 t_f = int(feed_tt[-1]) if take else None
                 escrow: dict[int, _OracleA1] = {}
-                for i in pend:
-                    orc = self._escrow.get(i)
-                    if orc is None:
-                        orc = _OracleA1(
-                            self.eps.etypes[i], self.eps.tlo[i],
-                            self.eps.thi[i],
-                            _lists_from_slots(self._bstate["s"][i],
-                                              self._bstate["ptr"][i]),
-                            int(self._bstate["count"][i]))
-                    orc.feed(feed_t, feed_tt)
+                orcs, fed = self._recount(pend, t_all, tt_all, take)
+                sp.note(fed=fed)
+                for i, orc in zip(pend, orcs):
                     cnt[i] = orc.count
                     lists = orc.pruned(t_f) if t_f is not None else orc.lists
                     fit = _slots_from_lists(lists, self.lcap)
@@ -829,6 +863,7 @@ class StreamingCounter:
                         s[i], ptr[i] = fit
                         ovf[i] = False
                 self._escrow = escrow
+        self._memo = {}  # carried from the old base
         self._bstate = {"s": s, "ptr": ptr, "count": cnt, "ovf": ovf}
         self._base_consumed += take
         self._suffix = ([(t_all[take:], tt_all[take:])]
@@ -972,6 +1007,7 @@ class StreamingCounter:
                                      d[f"suffix/{j}/tt"].astype(np.int32)))
                 j += 1
             self._escrow = {}
+            self._memo = {}
             for i in sorted({int(k.split("/")[1]) for k in d
                              if k.startswith("escrow/")}):
                 lists, j = [], 0

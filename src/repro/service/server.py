@@ -201,6 +201,12 @@ class MiningService:
             "recount_episodes": int(
                 REGISTRY.counter("stream_recount_episodes_total").value
             ),
+            "recount_carried": int(
+                REGISTRY.counter("stream_recount_carried_total").value
+            ),
+            "recount_events": int(
+                REGISTRY.counter("stream_recount_events_total").value
+            ),
         }
         out["daemon"] = {
             "heartbeat_ts": float(REGISTRY.gauge("daemon_heartbeat_ts").value),

@@ -279,7 +279,8 @@ _WINDOW_SPANS = {
     "stream.counter_init": (("session.mine_window",), {"kind", "m"}),
     "stream.replay": (("session.mine_window",), {"windows"}),
     "stream.readback": (("session.mine_window", "ckpt.state"), {"m"}),
-    "stream.recount": (("session.mine_window",), {"episodes", "events"}),
+    "stream.recount": (("session.mine_window",),
+                       {"episodes", "events", "fed"}),
     "ckpt.state": (("service.checkpoint", "schedule.snapshot",
                     "schedule.stage"), {"leaves"}),
     "ckpt.write": (("service.checkpoint",), {"leaves", "bytes"}),
@@ -293,6 +294,8 @@ def test_window_life_spans_nest_and_carry_args(tmp_path):
     the spans' arguments."""
     TRACER.clear()
     recounted = REGISTRY.counter("stream_recount_episodes_total").value
+    carried = REGISTRY.counter("stream_recount_carried_total").value
+    stepped = REGISTRY.counter("stream_recount_events_total").value
     written = REGISTRY.counter("checkpoint_bytes_total").value
     svc = MiningService(policy=SchedulerPolicy(max_sessions=2))
     # lcap 1 overflows the bounded lists, so episodes are recounted;
@@ -328,6 +331,11 @@ def test_window_life_spans_nest_and_carry_args(tmp_path):
     stats = svc.stats()
     assert (stats["streaming"]["recount_episodes"] - recounted
             == sum(e.args["episodes"] for e in evs if e.name == "stream.recount") > 0)
+    # flagged episodes' oracles are carried from read to read, and only
+    # the events of their own channels are stepped
+    assert stats["streaming"]["recount_carried"] - carried > 0
+    assert (stats["streaming"]["recount_events"] - stepped
+            == sum(e.args["fed"] for e in evs if e.name == "stream.recount") > 0)
     assert (stats["recovery"]["checkpoint_bytes"] - written
             == sum(e.args["bytes"] for e in evs if e.name == "ckpt.write") > 0)
 

@@ -291,3 +291,119 @@ def test_miner_counters_share_one_attribution_frontier(monkeypatch):
     assert whole
     for et in whole:
         assert sum(every[et]) == cum[(len(windows) - 1, et)]
+
+
+def _walk_all_lists(types, times, et, tlo, thi):
+    """Algorithm 1 for one episode, keeping every list entry (dead ones
+    too): the plain form the oracle's dropping of dead entries must
+    match on the live ones."""
+    n = len(et)
+    s, count = [[] for _ in range(n)], 0
+    for e, t in zip(types.tolist(), times.tolist()):
+        for i in range(n - 1, -1, -1):
+            if e != et[i]:
+                continue
+            if i == 0:
+                s[0].append(t)
+                continue
+            hit = next((v for v in reversed(s[i - 1])
+                        if tlo[i - 1] < t - v <= thi[i - 1]), None)
+            if hit is None:
+                continue
+            if i == n - 1:
+                count += 1
+                s = [[] for _ in range(n)]
+                break
+            s[i].append(t)
+    return count, s
+
+
+@pytest.mark.parametrize("seed", range(6))
+@pytest.mark.parametrize("episode", [
+    ([0, 1, 2], [1, 0], [5, 6]),
+    ([2, 2, 0], [0, 0], [3, 3]),
+    ([4, 0, 1, 3], [0, 1, 0], [6, 2, 4])])
+def test_oracle_chunked_and_filtered_feeds_agree(seed, episode):
+    """``_OracleA1`` fed a random stream in random chunks, with or without
+    the events of channels outside the episode, ends with the count of one
+    feed of the whole stream and the same live lists after ``pruned()``."""
+    from repro.core.streaming import _OracleA1
+
+    et, tlo, thi = episode
+    rng = np.random.default_rng(seed)
+    n = 600
+    times = (np.cumsum(rng.choice([0, 0, 1, 2, 3], size=n)) + 1).astype(
+        np.int32)
+    types = rng.integers(0, 8, size=n).astype(np.int32)
+    own = np.isin(types, et)
+    want_count, want_lists = _walk_all_lists(types, times, et, tlo, thi)
+    frontier = int(times[-1])
+    live = [[v for v in lst if frontier - v <= thi[i]]
+            for i, lst in enumerate(want_lists[:-1])] + [[]]
+    eps = EpisodeBatch(np.int32([et]), np.int32([tlo]), np.int32([thi]))
+    assert want_count == count_a1_sequential(
+        EventStream(types, times, 8), eps)[0]
+    for ty, tt in ((types, times), (types[own], times[own])):
+        whole = _OracleA1(et, tlo, thi)
+        assert whole.feed(ty, tt) == int(own.sum())
+        chunked = _OracleA1(et, tlo, thi)
+        cuts = np.sort(rng.choice(ty.size + 1, size=7))
+        for a, b in zip(np.r_[0, cuts], np.r_[cuts, ty.size]):
+            chunked.feed(ty[a:b], tt[a:b])
+        for orc in (whole, chunked):
+            assert orc.count == want_count
+            assert orc.pruned(frontier) == live
+
+
+@pytest.mark.parametrize("engine", ["ptpe", "mapconcatenate"])
+@pytest.mark.parametrize("lcap", [1, 4])
+@pytest.mark.parametrize("interval", [2, 8])
+def test_bounded_recount_exact_after_every_update(engine, lcap, interval):
+    """A bounded counter whose flagged episodes' oracles are carried from
+    read to read reports, after every window, the sequential count of the
+    prefix it has consumed; a twin restored from its ``state_dict`` (which
+    holds no carried oracle) mid-interval reads and continues the same, and
+    so does a used counter rolled back to that state."""
+    from repro.obs import REGISTRY
+
+    stream = tie_heavy_stream(9, n=480)
+    eps = batch()
+    wins = split_by_index(stream, 13)  # index cuts land inside tie groups
+    carried = REGISTRY.counter("stream_recount_carried_total").value
+    ctr = StreamingCounter(eps, engine=engine, lcap=lcap,
+                           checkpoint_interval=interval)
+    twin = early = None
+    outs = []
+    for j, w in enumerate(wins):
+        final = j == len(wins) - 1
+        got = ctr.update(w, final=final)
+        outs.append(got)
+        if final:
+            end = stream.times.size
+        elif engine == "ptpe":  # the trailing tie group is held back
+            end = int(np.searchsorted(stream.times, w.times[-1]))
+        else:  # committed up to the frontier τ_c
+            end = int(np.searchsorted(stream.times, ctr._tau_c, "right"))
+        want = count_a1_sequential(
+            EventStream(stream.types[:end], stream.times[:end], NUM_TYPES),
+            eps)
+        np.testing.assert_array_equal(got, want, err_msg=f"window {j}")
+        fresh = StreamingCounter(eps, engine=engine, lcap=lcap,
+                                 checkpoint_interval=interval)
+        fresh.load_state_dict(ctr.state_dict())
+        np.testing.assert_array_equal(fresh.counts(), got)
+        if twin is not None:
+            np.testing.assert_array_equal(twin.update(w, final=final), got)
+        elif j == 2:  # mid-interval for both intervals
+            twin, early = fresh, ctr.state_dict()
+    assert twin is not None
+    rolled = StreamingCounter(eps, engine=engine, lcap=lcap,
+                              checkpoint_interval=interval)
+    for w in wins[:11]:  # mid-interval again, oracles carried
+        rolled.update(w)
+    rolled.load_state_dict(early)
+    for j in range(3, len(wins)):
+        np.testing.assert_array_equal(
+            rolled.update(wins[j], final=j == len(wins) - 1), outs[j])
+    if lcap == 1:
+        assert REGISTRY.counter("stream_recount_carried_total").value > carried
