@@ -25,9 +25,9 @@ windows' support. The reference checks it from both sides:
   the program's, so each of them is due.
 
 Level 2 is counted for all channel pairs at once (``pair_counts``), level 3
-and up one episode at a time (``chain_counts``). Both are closed forms of
-Algorithm 1; the tests hold them equal to ``a1_counts``, the sequential
-Algorithm 1 copied from the paper's pseudocode.
+and up a level's candidates at a time (``chain_counts``). Both are closed
+forms of Algorithm 1; the tests hold them equal to ``a1_counts``, the
+sequential Algorithm 1 copied from the paper's pseudocode.
 """
 
 from __future__ import annotations
@@ -78,35 +78,67 @@ def a1_counts(types: np.ndarray, times: np.ndarray, et, tlo, thi,
     return out
 
 
-def chain_counts(types: np.ndarray, times: np.ndarray, et, tlo, thi,
-                 frontiers: np.ndarray) -> np.ndarray:
-    """Algorithm 1 on one serial episode, as ``a1_counts``, in closed form.
+def chain_counts(types: np.ndarray, times: np.ndarray, eps, tlo, thi,
+                 frontiers: np.ndarray, pos: dict | None = None) -> np.ndarray:
+    """Algorithm 1 on each serial episode of ``eps`` (one length, the same
+    edges (tlo, thi]), as ``a1_counts``, in closed form:
+    int64[len(eps), len(frontiers)].
 
     Since the last completion at position r, the machine's level-i list
     holds exactly the events of channel et[i] after r that end a chain of
     witnesses starting at a level-0 event after r. So give each event the
     newest start of such a chain (``q``: its own position at level 0, the
     largest ``q`` of its witnesses above): an event completes the episode
-    when its ``q`` exceeds r, and r moves to it."""
-    n = len(et)
-    pos = [np.nonzero(types == e)[0] for e in et]
-    q = pos[0]
-    for i in range(1, n):
-        t_prev, t_cur = times[pos[i - 1]], times[pos[i]].astype(np.int64)
-        a = np.searchsorted(t_prev, t_cur - int(thi[i - 1]), side="left")
-        b = np.searchsorted(t_prev, t_cur - int(tlo[i - 1]), side="left")
-        width = b - a  # witnesses: tlo < t - t_prev <= thi
-        qi = np.full(len(t_cur), -1, np.int64)
-        for d in range(int(width.max()) if len(width) else 0):
-            ok = d < width
-            qi[ok] = np.maximum(qi[ok], q[a[ok] + d])
-        q = qi
-    done, r = [], -1
-    for k, qk in zip(pos[-1].tolist(), q.tolist()):
-        if qk > r:
-            done.append(k)
-            r = k
-    return np.searchsorted(np.asarray(done, np.int64), frontiers, side="left")
+    when its ``q`` exceeds r, and r moves to it. A start lies before its
+    event, so every event up to r has ``q`` below r, and the next
+    completion is the first event whose running maximum of ``q`` exceeds
+    r. Episodes that share a prefix share its ``q``. ``pos`` maps a
+    channel to its event positions, where the caller has them."""
+    if pos is None:
+        pos = {c: np.nonzero(types == c)[0] for c in {x for ep in eps for x in ep}}
+    prefixes: dict[tuple, np.ndarray] = {}
+    spans: dict[tuple, tuple] = {}
+
+    def witnesses(prev, cur, i):
+        """Each event of ``cur`` has as witnesses the events of ``prev``
+        a[k]:b[k] (tlo < t - t_prev <= thi)."""
+        key = (prev, cur, i)
+        if key not in spans:
+            t_prev, t_cur = times[pos[prev]], times[pos[cur]].astype(np.int64)
+            spans[key] = (np.searchsorted(t_prev, t_cur - int(thi[i - 1]), side="left"),
+                          np.searchsorted(t_prev, t_cur - int(tlo[i - 1]), side="left"))
+        return spans[key]
+
+    def newest_start(ep):
+        if len(ep) == 1:
+            return pos[ep[0]]
+        q = prefixes.get(ep[:-1])
+        if q is None:
+            q = prefixes[ep[:-1]] = newest_start(ep[:-1])
+        a, b = witnesses(ep[-2], ep[-1], len(ep) - 1)
+        if not len(a):
+            return a
+        # the largest q over each a[k]:b[k], one reduction over all spans
+        # (a -1 closes q, so a span that ends there is in range)
+        bounds = np.empty(2 * len(a), np.int64)
+        bounds[0::2], bounds[1::2] = a, b
+        top = np.maximum.reduceat(np.append(q, -1), bounds)[0::2]
+        return np.where(b > a, top, -1)
+
+    out = np.zeros((len(eps), len(frontiers)), np.int64)
+    for m, ep in enumerate(eps):
+        last = pos[ep[-1]]
+        if not len(last):
+            continue
+        top = np.maximum.accumulate(newest_start(tuple(ep)))
+        nxt = np.searchsorted(top, last, side="right").tolist()
+        done, n = [], len(nxt)
+        j = int(np.searchsorted(top, -1, side="right"))
+        while j < n:
+            done.append(j)
+            j = nxt[j]
+        out[m] = np.searchsorted(last[done], frontiers, side="left")
+    return out
 
 
 def pair_counts(types: np.ndarray, times: np.ndarray, num_types: int, lo: int,
@@ -170,7 +202,18 @@ class ArrayReference:
         self.n_windows = n_windows
         self._pairs = pair_counts(self.types, self.times, num_types, self.lo,
                                   self.hi, self.frontiers)
+        self._pos = {c: np.nonzero(self.types == c)[0] for c in range(num_types)}
         self._memo: dict[tuple, np.ndarray] = {}
+
+    def _count(self, eps: list[tuple]) -> None:
+        """Count the episodes of ``eps`` (one length, 3 or more) that are
+        not counted yet, in one batch."""
+        todo = [ep for ep in eps if ep not in self._memo]
+        if todo:
+            k = len(todo[0]) - 1
+            counts = chain_counts(self.types, self.times, todo, [self.lo] * k,
+                                  [self.hi] * k, self.frontiers, self._pos)
+            self._memo.update(zip(todo, counts))
 
     def growth(self, ep: tuple, p: int) -> int:
         """Growth of episode ``ep`` (channel tuple) in window ``p``."""
@@ -180,12 +223,8 @@ class ArrayReference:
         if len(ep) == 2:
             c = self._pairs[:, ep[0], ep[1]]
         else:
-            c = self._memo.get(ep)
-            if c is None:
-                k = len(ep) - 1
-                c = self._memo[ep] = chain_counts(
-                    self.types, self.times, ep, [self.lo] * k, [self.hi] * k,
-                    self.frontiers)
+            self._count([ep])
+            c = self._memo[ep]
         return int(c[p] - (c[p - 1] if p else 0))
 
     def due(self, p: int) -> dict[tuple, int]:
@@ -204,7 +243,9 @@ class ArrayReference:
         while level and k < self.max_level:
             k += 1
             nxt = []
-            for ep in _join(level):
+            cands = _join(level)
+            self._count(cands)
+            for ep in cands:
                 g = self.growth(ep, p)
                 if g >= self.theta:
                     out[ep] = g

@@ -16,9 +16,13 @@ Faults, each planted in the program before the run starts:
   control          the plain reference's guarantee broken the way a cheaper
                    counter would: bounded lists of two slots per level and no
                    exact recount of the episodes whose lists overflowed
+  no_recount       the configuration's own bounded lists, with that exact
+                   recount switched off
 
-Every one of them has to make the run report ``"correct": false``. The
-control runs on the chip too, at the cell's size (``PERF.md``).
+Every one of them has to make the run report ``"correct": false``, on
+traffic that can show it: ``no_recount`` only where the lists overflow,
+as in bursts (``bench/tests/test_bench_bursts.py``). The control runs on
+the chip too, at the cell's size (``PERF.md``).
 """
 
 import os
@@ -79,20 +83,21 @@ def plant(fault: str) -> None:
                 out.append(state["first"])
             return out
         MiningClient.poll = twice
-    elif fault == "control":
+    elif fault in ("no_recount", "control"):
         streaming.StreamingCounter._restore_exact_bounded = (
             lambda self, c, flagged: c)
-        real = run.session_config
-        run.session_config = lambda cfg, traffic: dataclasses.replace(
-            real(cfg, traffic), lcap=2)
-        if "--rehearse" in sys.argv:
-            # two slots overflow only at the configuration's own rates: keep
-            # them in the rehearsal, counted by the XLA scans (the same
-            # bounded-list machines as the kernels, far faster on the CPU)
-            os.environ.pop("REPRO_KERNEL_INTERPRET", None)
-            scaled = run.scaled
-            run.scaled = lambda cell, rehearse: (
-                cell.config, {**scaled(cell, rehearse)[1], "warmup_windows": 1})
+        if fault == "control":
+            real = run.session_config
+            run.session_config = lambda cfg, traffic: dataclasses.replace(
+                real(cfg, traffic), lcap=2)
+            if "--rehearse" in sys.argv:
+                # two slots overflow only at the configuration's own rates: keep
+                # them in the rehearsal, counted by the XLA scans (the same
+                # bounded-list machines as the kernels, far faster on the CPU)
+                os.environ.pop("REPRO_KERNEL_INTERPRET", None)
+                scaled = run.scaled
+                run.scaled = lambda cell, rehearse: (
+                    cell.config, {**scaled(cell, rehearse)[1], "warmup_windows": 1})
     else:
         raise SystemExit(f"unknown fault {fault!r}")
 

@@ -81,10 +81,15 @@ def test_open_loop_keeps_its_schedule():
 
 
 def test_a_stalled_server_shows_as_latency_not_as_a_late_sender():
+    stall_s = 0.6
     steady = run(OPEN, [FakeClient(0.02)], 1.5)
-    stalled = run(OPEN, [FakeClient(0.02, time.perf_counter() + 0.5, 0.6)], 1.5)
-    lag = [s.sent - s.due for s in stalled.records()]
-    assert max(lag) < 0.05  # the sender kept its schedule
+    stalled = run(OPEN, [FakeClient(0.02, time.perf_counter() + 0.5, stall_s)], 1.5)
+    # the sender kept its schedule: the stall made it no later than the
+    # steady run's sender on the same busy machine. One that waited for
+    # the server would fall most of the stall behind.
+    lag = {name: max(s.sent - s.due for s in r.records())
+           for name, r in (("steady", steady), ("stalled", stalled))}
+    assert lag["stalled"] < lag["steady"] + stall_s / 4, lag
     assert len(stalled.due()) == len(steady.due())
     assert (measure.percentile(measure.latency_s(stalled), 90)
             > measure.percentile(measure.latency_s(steady), 90) + 0.3)

@@ -1,7 +1,8 @@
 """The plain reference: its two counters agree with each other and with a
-brute-force non-overlapped count, and the comparison flags every kind of
-wrong delta."""
+brute-force non-overlapped count, on uniform streams and on bursting ones,
+and the comparison flags every kind of wrong delta."""
 
+import json
 import sys
 from pathlib import Path
 
@@ -15,12 +16,41 @@ import streams  # noqa: E402
 
 SYM = {"num_types": 6, "rate_hz": 40.0, "interval_ms": [5, 10],
        "chains": [{"types": [0, 1, 2], "rate_hz": 10.0}]}
+BURSTS = json.loads((Path(__file__).parent / "data" / "burst_culture.json").read_text())
 
 
 def _random_stream(seed, n=400, types=4, t_max=600):
     rng = np.random.default_rng(seed)
     times = np.sort(rng.integers(0, t_max, n)).astype(np.int32)  # many ties
     return rng.integers(0, types, n).astype(np.int32), times
+
+
+def _burst_stream(name, types, seconds=15.0):
+    """A culture's recording (the burst fixture) on ``types`` channels,
+    every one recruited by every burst: channels fire together in one tick
+    and each fires more than 4 times within 12 ms, so an A1 list holds more
+    than 4 partial occurrences."""
+    cfg = {**BURSTS, "num_types": types,
+           "bursts": {**BURSTS["bursts"], "recruited": 1.0}}
+    k = int(name.removeprefix("bursts-"))
+    rec = streams.spike_train(cfg, seconds, streams.array_rng(k, types))
+    t = rec.times.astype(np.int64)
+    assert np.count_nonzero(np.diff(t) == 0) > len(t) // 10  # cross-channel ties
+    assert max(np.count_nonzero(t[rec.types == c][4:] - t[rec.types == c][:-4] <= 12)
+               for c in range(types)) > 0
+    return rec.types, rec.times
+
+
+def _stream(name, n, types, t_max):
+    """Seeds (ints) name uniform random streams, "bursts-<k>" a culture."""
+    if isinstance(name, str):
+        return _burst_stream(name, types)
+    return _random_stream(name, n, types, t_max)
+
+
+def _frontiers(n, marks, base):
+    """``marks`` set for a stream of ``base`` events, scaled to ``n``."""
+    return np.array([0, *(m * n // base for m in marks), n])
 
 
 def _greedy(types, times, et, lo, hi):
@@ -55,10 +85,10 @@ def _greedy(types, times, et, lo, hi):
         start = best + 1
 
 
-@pytest.mark.parametrize("seed", range(6))
+@pytest.mark.parametrize("seed", [*range(6), "bursts-0", "bursts-1", "bursts-2"])
 def test_pair_counts_equal_algorithm_1(seed):
-    types, times = _random_stream(seed)
-    frontiers = np.array([0, 50, 133, 290, 400])
+    types, times = _stream(seed, 400, 4, 600)
+    frontiers = _frontiers(len(types), [50, 133, 290], 400)
     pairs = reference.pair_counts(types, times, 4, 1, 12, frontiers)
     for a in range(4):
         for b in range(4):
@@ -66,19 +96,26 @@ def test_pair_counts_equal_algorithm_1(seed):
             assert np.array_equal(pairs[:, a, b], want), (a, b)
 
 
-@pytest.mark.parametrize("seed", range(6))
+@pytest.mark.parametrize("seed", [*range(6), "bursts-0", "bursts-1", "bursts-2"])
 def test_chain_counts_equal_algorithm_1(seed):
-    types, times = _random_stream(200 + seed, n=500, types=3, t_max=900)
-    frontiers = np.array([0, 7, 100, 250, 499, 500])
-    for et in [(0, 1), (1, 1), (0, 1, 2), (0, 0, 1), (2, 1, 2), (1, 1, 1),
-               (2, 0, 1, 2), (0, 1, 0, 1)]:
-        k = len(et) - 1
-        for lo, hi in [(1, 12), (0, 5), (5, 10)]:
-            want = reference.a1_counts(types, times, et, [lo] * k, [hi] * k,
-                                       frontiers)
-            got = reference.chain_counts(types, times, et, [lo] * k, [hi] * k,
-                                         frontiers)
-            assert np.array_equal(got, want), (et, lo, hi)
+    types, times = _stream(seed if isinstance(seed, str) else 200 + seed, 500, 3, 900)
+    frontiers = _frontiers(len(types), [7, 100, 250, 499], 500)
+    episodes = [(0, 1), (1, 1), (0, 1, 2), (0, 0, 1), (2, 1, 2), (1, 1, 1),
+                (0, 1, 0), (0, 1, 1), (2, 0, 1, 2), (0, 1, 0, 1), (0, 1, 0, 2)]
+    for lo, hi in [(1, 12), (0, 5), (5, 10)]:
+        for n in (2, 3, 4):
+            # one batch per length, where episodes share prefixes
+            eps = [et for et in episodes if len(et) == n]
+            k = n - 1
+            batch = reference.chain_counts(types, times, eps, [lo] * k, [hi] * k,
+                                           frontiers)
+            for et, in_batch in zip(eps, batch):
+                want = reference.a1_counts(types, times, et, [lo] * k, [hi] * k,
+                                           frontiers)
+                alone = reference.chain_counts(types, times, [et], [lo] * k,
+                                               [hi] * k, frontiers)[0]
+                assert np.array_equal(alone, want), (et, lo, hi)
+                assert np.array_equal(in_batch, want), (et, lo, hi)
 
 
 @pytest.mark.parametrize("seed", range(4))
